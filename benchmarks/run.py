@@ -190,6 +190,9 @@ def main(argv=None) -> int:
                          "benchmarks/baselines/BENCH_*.json in one command; "
                          "refuses on a machine the jitter guard flags noisy")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+
+    print(f"[bench] compile cache: {compile_cache.enable()}")
     smoke = args.smoke or args.update_baselines
     quick = args.quick or smoke
     json_dir = args.json_dir or ("." if args.smoke else None)

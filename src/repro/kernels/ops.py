@@ -129,7 +129,7 @@ def quant_dense_apply(x: jax.Array, codes: jax.Array, scale: jax.Array, *,
     (K, N/2) packed-int4 uint8; scale (1, N) f32 (zipml grids pre-divide by
     s). Leading x dims fold into the GEMM M axis; every dim pads to 128
     multiples (zero padding is exact: padded x/g entries are 0, padded
-    output rows/cols are sliced off) and packed planes pad bytewise.
+    output rows/cols are sliced off) and packed planes pad to 128 bytes.
     """
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
@@ -138,10 +138,10 @@ def quant_dense_apply(x: jax.Array, codes: jax.Array, scale: jax.Array, *,
     n0 = codes.shape[1] * (2 if packed else 1)
     pdiv = 2 if packed else 1
     x2, _ = _pad_to(x2, 128, 0)
-    x2, _ = _pad_to(x2, 128, 1)
+    x2, _ = _pad_to(x2, 128 * pdiv if transpose else 128, 1)
     codes, _ = _pad_to(codes, 128, 0)
-    codes, _ = _pad_to(codes, 128 // pdiv, 1)
-    scale, _ = _pad_to(scale, 128, 1)
+    codes, _ = _pad_to(codes, 128, 1)
+    scale, _ = _pad_to(scale, 128 * pdiv, 1)
     if transpose:
         y = qmm_mod.qmm_t(x2, codes, scale, packed=packed)
         return y[:m0, :k0].reshape(*lead, k0)
@@ -156,17 +156,19 @@ def quant_dense_bitplane(x: jax.Array, codes: jax.Array, scale: jax.Array,
     x: (*lead, K); codes (P, K, W) uint32 with W = ⌈n_out/32⌉ (plane 0 =
     sign, then magnitude MSB-first); scale (1, n_out) f32. Leading x dims
     fold into the GEMM M axis; M/K pad to 128 multiples and the word axis
-    to 4-word (128-column) multiples — zero words decode to +0·scale, and
-    the padded output columns are sliced off.
+    to 4-word (128-column) multiples, or past 128 words to 128-word ones
+    (the kernel's lane-dense word block) — zero words decode to +0·scale,
+    and the padded output columns are sliced off.
     """
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     m0 = x2.shape[0]
     x2, _ = _pad_to(x2, 128, 0)
     x2, _ = _pad_to(x2, 128, 1)
+    words = 128 if codes.shape[2] > 128 else 4
     codes, _ = _pad_to(codes, 128, 1)
-    codes, _ = _pad_to(codes, 4, 2)
-    scale, _ = _pad_to(scale, 128, 1)
+    codes, _ = _pad_to(codes, words, 2)
+    scale, _ = _pad_to(scale, 32 * words, 1)
     y = qbp_mod.qmm_bitplane(x2, codes, scale)
     return y[:m0, :n_out].reshape(*lead, n_out)
 

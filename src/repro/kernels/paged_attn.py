@@ -11,7 +11,8 @@ dequantize by **split nibbles fused into the flash inner loop**: scores and
 the value accumulator work on the even/odd D-halves directly (both are
 half-sum-decomposable), so the per-page stride interleave the old
 unpack-then-attend path paid — the int4-slower-than-int8 regression — is
-gone; the output interleaves once at the end.
+gone. q enters and the output leaves as [even | odd] D-halves, permuted by
+XLA outside the kernel (Mosaic has no in-kernel lane interleave).
 
 Mechanics:
 * grid = (B, MAXP); the page axis is the sequential minor axis, so the f32
@@ -63,10 +64,12 @@ def _nibble_halves(codes, scale):
     The shift+mask runs on the packed words in place — no interleave, no
     (page, Hkv, D) stride scatter. The caller keeps the two halves apart
     through the whole page loop; scores and the value accumulator are
-    half-sum-decomposable, so only the final output interleaves (once)."""
+    half-sum-decomposable, so only the final output interleaves (once, in
+    XLA after the kernel)."""
     s = scale.astype(jnp.float32)
-    lo = ((codes & 0xF).astype(jnp.float32) - 8.0) * s
-    hi = (((codes >> 4) & 0xF).astype(jnp.float32) - 8.0) * s
+    c = codes.astype(jnp.int32)     # Mosaic casts no unsigned int to float
+    lo = ((c & 0xF) - 8).astype(jnp.float32) * s
+    hi = ((c >> 4) - 8).astype(jnp.float32) * s
     return lo, hi
 
 
@@ -87,15 +90,16 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref,
     if kv_bits == 4:
         # fused in-register nibble dequant: scores split over the even/odd
         # D-halves (the D-sum is permutation-invariant), V accumulated
-        # de-interleaved — the interleave happens once, in _finish
+        # de-interleaved — the wrapper interleaves the output once
         k_lo, k_hi = _nibble_halves(kp_ref[0], ks_ref[0])   # (page, G, D/2)
         v_lo, v_hi = _nibble_halves(vp_ref[0], vs_ref[0])
         g = k_lo.shape[1]
         r = h // g
-        qr = q.reshape(g, r, d // 2, 2)
-        s = (jnp.einsum("grd,tgd->grt", qr[..., 0], k_lo,
+        d2 = d // 2
+        qg = q.reshape(g, r, d)                 # q arrives as [even | odd]
+        s = (jnp.einsum("grd,tgd->grt", qg[..., :d2], k_lo,
                         preferred_element_type=jnp.float32)
-             + jnp.einsum("grd,tgd->grt", qr[..., 1], k_hi,
+             + jnp.einsum("grd,tgd->grt", qg[..., d2:], k_hi,
                           preferred_element_type=jnp.float32)) * softmax_scale
     else:
         k = _dequant(kp_ref[0], ks_ref[0], kv_bits)     # (page, G, D)
@@ -138,9 +142,6 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref,
         # floor → output 0; those rows are never read by the engine
         l = jnp.maximum(l_ref[...], 1e-30)[..., None]   # (G, R, 1)
         out = acc_ref[...].reshape(g, r, d) / l
-        if kv_bits == 4:
-            # the one interleave: [even | odd] halves → natural D order
-            out = out.reshape(g, r, 2, d // 2).swapaxes(-1, -2)
         o_ref[0] = out.reshape(h, d)
 
 
@@ -192,9 +193,16 @@ def paged_decode_attn(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     )
     kernel = functools.partial(_paged_attn_kernel, softmax_scale=softmax_scale,
                                kv_bits=kv_bits, page=page)
-    return pl.pallas_call(
+    if kv_bits == 4:
+        # Mosaic cannot (de)interleave lanes in a kernel: q goes in, and the
+        # output comes out, as [even | odd] D-halves; XLA permutes both
+        q = jnp.concatenate([q[..., 0::2], q[..., 1::2]], axis=-1)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
         interpret=registry.resolve_interpret(interpret),
     )(bt, lens, q, k_pages, v_pages, k_scale, v_scale)
+    if kv_bits == 4:
+        out = out.reshape(b, h, 2, d // 2).swapaxes(-1, -2).reshape(b, h, d)
+    return out

@@ -28,6 +28,12 @@ blocks are fitted so every grid axis tiles its dim exactly. ``qmm_qout``
 holds a (bm, N) accumulator (N unblocked), so its VMEM bound is
 bm·N·(4+4+2·1) bytes — callers cap bm accordingly.
 
+Packed int4 never interleaves lanes in a kernel (Mosaic cannot): a packed
+block unpacks to its even and odd columns, which the kernels keep as two
+planes. Scales, dy and rand enter split that way and outputs leave that way;
+the wrappers permute them in XLA, and a packed ``bn`` is at least 256 so a
+block's 128 code bytes fill the lanes.
+
 ``interpret=None`` resolves through :func:`repro.kernels.registry.
 interpret_default` — the ONE place deciding real-compile vs interpret mode.
 """
@@ -43,24 +49,46 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import registry
 
 
-def _dequant_block(w, scale, *, packed: bool):
-    """codes block (bk, bn[/2]) + scale (1, bn) → dequantized (bk, bn) f32.
+def _dequant_block(w, scale):
+    """int8 codes block (bk, bn) + scale (1, bn) → dequantized (bk, bn) f32.
 
     Dequantizes at full f32 precision in VMEM — the same values as
     ``QTensor.decode()`` (f32 default), i.e. strictly *more* accurate than
     the ref backend's bf16 decode-then-einsum, whose decoded weight carries
     one bf16 rounding. Parity vs ref is therefore bounded by bf16 epsilon;
     the code-domain gradient matches the f32 decode path to f32-accumulation
-    associativity (≤ 1e-5 rel — the bench CHECK). Packed int4 planes unpack
-    through the canonical :func:`repro.quant.unpack_int4` (pure jnp — traces
-    inside the kernel body)."""
-    if packed:
-        from repro.quant import unpack_int4
+    associativity (≤ 1e-5 rel — the bench CHECK)."""
+    return w.astype(jnp.float32) * scale.astype(jnp.float32)
 
-        x = unpack_int4(w)
-    else:
-        x = w.astype(jnp.float32)
-    return x * scale.astype(jnp.float32)
+
+def _nibble_halves(w, scale2):
+    """Packed-int4 block (bk, bn/2) uint8 + split scale (2, bn/2) → the
+    (even, odd) column halves, each (bk, bn/2) f32 — pack_int4's offset
+    nibbles, unpacked in place. Mosaic has no in-kernel lane interleave, so
+    the halves stay apart: packed kernels take and give [even, odd] planes
+    that the wrappers (:func:`_split_cols` / :func:`_join_cols`) permute."""
+    c = w.astype(jnp.int32)          # Mosaic casts no unsigned int to float
+    s = scale2.astype(jnp.float32)
+    return (((c & 0xF) - 8).astype(jnp.float32) * s[0:1],
+            ((c >> 4) - 8).astype(jnp.float32) * s[1:2])
+
+
+def _split_cols(a):
+    """(…, N) → (2, …, N/2): the even and the odd columns."""
+    a = a.reshape(*a.shape[:-1], a.shape[-1] // 2, 2)
+    return jnp.moveaxis(a, -1, 0)
+
+
+def _join_cols(a2):
+    """Inverse of :func:`_split_cols`."""
+    a = jnp.moveaxis(a2, 0, -1)
+    return a.reshape(*a.shape[:-2], a.shape[-2] * 2)
+
+
+def _packed_bn(bn: int, n: int) -> int:
+    """A packed block's byte width bn/2 must fill the 128 lanes (or be the
+    whole array): lift narrower resolved blocks to 256 columns."""
+    return bn if bn % 256 == 0 or bn == n else registry.fit_block(256, n)
 
 
 def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, *, packed: bool):
@@ -70,9 +98,13 @@ def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, *, packed: bool):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]
-    w = _dequant_block(w_ref[...], scale_ref[...], packed=packed)
-    o_ref[...] += jnp.dot(x.astype(jnp.float32), w, preferred_element_type=jnp.float32)
+    x = x_ref[...].astype(jnp.float32)
+    if packed:
+        for half, w in enumerate(_nibble_halves(w_ref[...], scale_ref[...])):
+            o_ref[half] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    else:
+        w = _dequant_block(w_ref[...], scale_ref[...])
+        o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
 def _qmm_t_kernel(g_ref, w_ref, scale_ref, o_ref, *, packed: bool):
@@ -82,11 +114,18 @@ def _qmm_t_kernel(g_ref, w_ref, scale_ref, o_ref, *, packed: bool):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    g = g_ref[...]
-    w = _dequant_block(w_ref[...], scale_ref[...], packed=packed)
     # dx[bm, bk] += dy[bm, bn] · w[bk, bn]ᵀ — contraction over the N axis
-    o_ref[...] += jax.lax.dot_general(
-        g.astype(jnp.float32), w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    dims = (((1,), (1,)), ((), ()))
+    if packed:
+        for half, w in enumerate(_nibble_halves(w_ref[...], scale_ref[...])):
+            o_ref[...] += jax.lax.dot_general(
+                g_ref[half].astype(jnp.float32), w, dims,
+                preferred_element_type=jnp.float32)
+    else:
+        w = _dequant_block(w_ref[...], scale_ref[...])
+        o_ref[...] += jax.lax.dot_general(
+            g_ref[...].astype(jnp.float32), w, dims,
+            preferred_element_type=jnp.float32)
 
 
 def _qmv_kernel(c_ref, v_ref, o_ref):
@@ -153,20 +192,27 @@ def qmm(x: jax.Array, codes: jax.Array, scale: jax.Array, *,
         "qmm", {"bm": m, "bk": k, "bn": n},
         dtype="int4" if packed else "int8",
         explicit={"bm": bm, "bk": bk, "bn": bn})
-    pdiv = 2 if packed else 1
+    planes = 2 if packed else 1     # packed: [even, odd] column planes
+    if packed:
+        bn = _packed_bn(bn, n)
+        scale = _split_cols(scale)[:, 0]
+    w = bn // planes
+    lead = (planes,) if packed else ()
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
-    return pl.pallas_call(
+    y = pl.pallas_call(
         functools.partial(_qmm_kernel, packed=packed),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn // pdiv), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((bk, w), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((planes, w), lambda i, j, kk: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        out_specs=pl.BlockSpec((*lead, bm, w),
+                               lambda i, j, kk: (*(0,) * len(lead), i, j)),
+        out_shape=jax.ShapeDtypeStruct((*lead, m, n // planes), jnp.float32),
         interpret=interpret,
     )(x, codes, scale)
+    return _join_cols(y) if packed else y
 
 
 @functools.partial(jax.jit,
@@ -194,20 +240,29 @@ def qmm_t(g: jax.Array, codes: jax.Array, scale: jax.Array, *,
         "qmm_t", {"bm": m, "bk": k, "bn": n},
         dtype="int4" if packed else "int8",
         explicit={"bm": bm, "bk": bk, "bn": bn})
-    pdiv = 2 if packed else 1
+    planes = 2 if packed else 1     # packed: [even, odd] column planes
+    if packed:
+        bn = _packed_bn(bn, n)
+        g, scale = _split_cols(g), _split_cols(scale)[:, 0]
+    w = bn // planes
+    lead = (planes,) if packed else ()
     grid = (pl.cdiv(m, bm), pl.cdiv(k, bk), pl.cdiv(n, bn))
     return pl.pallas_call(
         functools.partial(_qmm_t_kernel, packed=packed),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, nn: (i, nn)),
-            pl.BlockSpec((bk, bn // pdiv), lambda i, j, nn: (j, nn)),
-            pl.BlockSpec((1, bn), lambda i, j, nn: (0, nn)),
+            pl.BlockSpec((*lead, bm, w),
+                         lambda i, j, nn: (*(0,) * len(lead), i, nn)),
+            pl.BlockSpec((bk, w), lambda i, j, nn: (j, nn)),
+            pl.BlockSpec((planes, w), lambda i, j, nn: (0, nn)),
         ],
         out_specs=pl.BlockSpec((bm, bk), lambda i, j, nn: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k), jnp.float32),
         interpret=interpret,
     )(g, codes, scale)
+
+
+_EPILOGUE_ROWS = 32
 
 
 def _qmm_qout_kernel(x_ref, w_ref, scale_ref, rand_ref, c1_ref, c2_ref,
@@ -230,27 +285,42 @@ def _qmm_qout_kernel(x_ref, w_ref, scale_ref, rand_ref, c1_ref, c2_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    w = _dequant_block(w_ref[...], scale_ref[...], packed=packed)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    if packed:
+        for half, w in enumerate(_nibble_halves(w_ref[...], scale_ref[...])):
+            acc_ref[half] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    else:
+        w = _dequant_block(w_ref[...], scale_ref[...])
+        acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(kk == nk - 1)
     def _epilogue():
-        # quantize the dtype-rounded activation (bf16 in the model) so the
-        # fused path matches einsum→astype(x.dtype)→quantize exactly
-        y = acc_ref[...].astype(out_dtype).astype(jnp.float32)
-        absmax = jnp.max(jnp.abs(y), axis=1, keepdims=True)       # (bm, 1)
-        scale = jnp.where(absmax == 0, 1.0, absmax / qmax)
-        t = y / scale
-        base = jnp.floor(t)
-        frac = t - base
-        u = rand_ref[...]
-        u1 = (u >> 16).astype(jnp.float32) * (1.0 / (1 << 16))
-        u2 = (u & 0xFFFF).astype(jnp.float32) * (1.0 / (1 << 16))
-        c1 = jnp.clip(base + (u1 < frac).astype(jnp.float32), -qmax, qmax)
-        c2 = jnp.clip(base + (u2 < frac).astype(jnp.float32), -qmax, qmax)
-        c1_ref[...] = c1.astype(jnp.int8)
-        c2_ref[...] = c2.astype(jnp.int8)
-        os_ref[...] = scale.astype(jnp.float32)
+        bm = os_ref.shape[0]
+        rows = _EPILOGUE_ROWS if bm % _EPILOGUE_ROWS == 0 else bm
+
+        def chunk(c, carry):
+            # row chunks keep the epilogue's full-width temporaries in VMEM
+            r = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            # quantize the dtype-rounded activation (bf16 in the model) so
+            # the fused path matches einsum→astype(x.dtype)→quantize exactly
+            y = acc_ref[..., r, :].astype(out_dtype).astype(jnp.float32)
+            absmax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
+            if packed:                          # over both column planes
+                absmax = jnp.maximum(absmax[0], absmax[1])
+            scale = jnp.where(absmax == 0, 1.0, absmax / qmax)    # (rows, 1)
+            t = y / scale
+            base = jnp.floor(t)
+            frac = t - base
+            u = rand_ref[..., r, :]
+            u1 = (u >> 16).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 16))
+            u2 = (u & 0xFFFF).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 16))
+            c1 = jnp.clip(base + (u1 < frac).astype(jnp.float32), -qmax, qmax)
+            c2 = jnp.clip(base + (u2 < frac).astype(jnp.float32), -qmax, qmax)
+            c1_ref[..., r, :] = c1.astype(jnp.int8)
+            c2_ref[..., r, :] = c2.astype(jnp.int8)
+            os_ref[r, :] = scale.astype(jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, bm // rows, chunk, 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -278,25 +348,32 @@ def qmm_qout(x: jax.Array, codes: jax.Array, scale: jax.Array,
     bm, bk = registry.resolve_block(
         "qmm_qout", {"bm": m, "bk": k},
         dtype="int4" if packed else "int8", explicit={"bm": bm, "bk": bk})
-    pdiv = 2 if packed else 1
+    planes = 2 if packed else 1     # packed: [even, odd] column planes
+    if packed:
+        scale, rand = _split_cols(scale)[:, 0], _split_cols(rand)
+    w = n // planes
+    lead = (planes,) if packed else ()
+    z = (0,) * len(lead)
     grid = (pl.cdiv(m, bm), pl.cdiv(k, bk))
-    out_block = pl.BlockSpec((bm, n), lambda i, kk: (i, 0))
+    out_block = pl.BlockSpec((*lead, bm, w), lambda i, kk: (*z, i, 0))
     c1, c2, oscale = pl.pallas_call(
         functools.partial(_qmm_qout_kernel, packed=packed, qmax=qmax,
                           out_dtype=out_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, kk: (i, kk)),
-            pl.BlockSpec((bk, n // pdiv), lambda i, kk: (kk, 0)),
-            pl.BlockSpec((1, n), lambda i, kk: (0, 0)),
-            pl.BlockSpec((bm, n), lambda i, kk: (i, 0)),
+            pl.BlockSpec((bk, w), lambda i, kk: (kk, 0)),
+            pl.BlockSpec((planes, w), lambda i, kk: (0, 0)),
+            out_block,
         ],
         out_specs=[out_block, out_block,
                    pl.BlockSpec((bm, 1), lambda i, kk: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((m, n), jnp.int8),
-                   jax.ShapeDtypeStruct((m, n), jnp.int8),
+        out_shape=[jax.ShapeDtypeStruct((*lead, m, w), jnp.int8),
+                   jax.ShapeDtypeStruct((*lead, m, w), jnp.int8),
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bm, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((*lead, bm, w), jnp.float32)],
         interpret=interpret,
     )(x, codes, scale, rand)
+    if packed:
+        c1, c2 = _join_cols(c1), _join_cols(c2)
     return c1, c2, oscale

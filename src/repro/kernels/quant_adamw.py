@@ -8,9 +8,9 @@ tensors in HBM along the way. The fused pipeline never materializes them:
 
 * pass 1 (``qadamw_absmax``) recomputes the new m / √v per tile in VMEM from
   (codes, scales, g) and emits only per-tile column absmaxes — the host
-  reduces those to the new quantization scales (the same trick as
-  ``stoch_quant.row_absmax``: cross-block accumulation is kept out of the
-  kernel so interpret mode stays bit-exact with TPU).
+  reduces those to the new quantization scales (the row-block axis is the
+  major grid axis, so a revisited accumulator block would not stay resident
+  across it).
 * pass 2 (``qadamw_update``) recomputes m / v again, writes the new fp32
   master and both int8 code planes in one VMEM pass.
 
@@ -73,13 +73,16 @@ def _moments(g, m_codes, m_scale, v_codes, v_scale, clip, finite,
 
 def _absmax_kernel(g_ref, mc_ref, ms_ref, vc_ref, vs_ref, par_ref,
                    mx_ref, vx_ref, *, b1: float, b2: float):
-    """Per-(row-block, col-block) column absmax of the *stored* new m and √v."""
+    """Per-(row-block, col-block) column absmax of the *stored* new m and √v,
+    written to all 8 sublanes of an (8, bc) block (the TPU's tile height)."""
     _, _, m_store, v_store = _moments(
         g_ref[...], mc_ref[...], ms_ref[...].astype(jnp.float32),
         vc_ref[...], vs_ref[...].astype(jnp.float32),
         par_ref[P_CLIP], par_ref[P_FINITE], b1=b1, b2=b2)
-    mx_ref[...] = jnp.max(jnp.abs(m_store), axis=0, keepdims=True)
-    vx_ref[...] = jnp.max(jnp.sqrt(v_store), axis=0, keepdims=True)
+    mx_ref[...] = jnp.broadcast_to(
+        jnp.max(jnp.abs(m_store), axis=0, keepdims=True), mx_ref.shape)
+    vx_ref[...] = jnp.broadcast_to(
+        jnp.max(jnp.sqrt(v_store), axis=0, keepdims=True), vx_ref.shape)
 
 
 def _update_kernel(mst_ref, g_ref, mc_ref, ms_ref, vc_ref, vs_ref,
@@ -104,8 +107,8 @@ def _update_kernel(mst_ref, g_ref, mc_ref, ms_ref, vc_ref, vs_ref,
     out_mst[...] = jnp.where(finite > 0, new_mst, mst)
     # stochastic re-encode: independent 16-bit up/down draws for m and √v
     u = rand_ref[...]
-    u1 = (u >> 16).astype(jnp.float32) * (1.0 / (1 << 16))
-    u2 = (u & 0xFFFF).astype(jnp.float32) * (1.0 / (1 << 16))
+    u1 = (u >> 16).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 16))
+    u2 = (u & 0xFFFF).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 16))
     m_t = m_store / msn_ref[...].astype(jnp.float32)
     lo = jnp.floor(m_t)
     mc = lo + (u1 < (m_t - lo)).astype(jnp.float32)
@@ -129,19 +132,20 @@ def qadamw_absmax(g, m_codes, m_scale, v_codes, v_scale, params, *,
                   b1: float, b2: float, block=None,
                   interpret: bool | None = None):
     """g (R, C) f32; codes (R, C) int8; scales (1, C) f32; params (8,) f32.
-    Returns per-row-block column absmaxes: (R/br, C) for new-m and new-√v."""
+    Returns per-row-block column absmaxes: (8·R/br, C) for new-m and new-√v
+    (each row block's maxima repeated on 8 rows)."""
     r, c = g.shape
     br, bc = _resolve(block, r, c)
     grid = (pl.cdiv(r, br), pl.cdiv(c, bc))
     tile, colrow, smem = _specs(br, bc)
-    out_spec = pl.BlockSpec((1, bc), lambda i, j: (i, j))
+    out_spec = pl.BlockSpec((8, bc), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_absmax_kernel, b1=b1, b2=b2),
         grid=grid,
         in_specs=[tile, tile, colrow, tile, colrow, smem],
         out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((grid[0], c), jnp.float32),
-                   jax.ShapeDtypeStruct((grid[0], c), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((8 * grid[0], c), jnp.float32),
+                   jax.ShapeDtypeStruct((8 * grid[0], c), jnp.float32)],
         interpret=registry.resolve_interpret(interpret),
     )(g, m_codes, m_scale, v_codes, v_scale, params)
 

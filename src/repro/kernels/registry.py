@@ -54,7 +54,7 @@ def resolve_interpret(interpret: bool | None) -> bool:
 # ``block=None → autotune-cache lookup → default`` resolution chain
 BLOCK_DEFAULTS = {
     "qmm": {"bm": 256, "bk": 512, "bn": 256},
-    "qmm_bitplane": {"bm": 256, "bk": 512, "bn": 256},
+    "qmm_bitplane": {"bm": 128, "bk": 256, "bn": 4096},
     "qmm_t": {"bm": 256, "bk": 256, "bn": 512},
     "qmm_qout": {"bm": 256, "bk": 512},
     "qmv": {"br": 256, "bc": 512},
@@ -129,6 +129,31 @@ def matmul_eq(x_ndim: int, w_ndim: int, transpose: bool = False) -> str:
     return f"{x_lbl},{stack}kn->{out}"
 
 
+def einsum_f32(eq: str, x, w):
+    """``jnp.einsum(eq, x, w)`` accumulating in f32: the form of every
+    ``quant_dense`` and MoE expert matmul.
+
+    XLA's CPU runtime has no bf16×bf16→f32 dot whose batch axes follow
+    another axis of x (the MoE ``"gecd,edf->gecf"`` form). On the CPU the
+    batch axes of x move to the front for the dot and back after it: the same
+    products and sums. Other backends run the einsum as written.
+    """
+    if jax.default_backend() != "cpu":
+        return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32)
+    ins, out = eq.split("->")
+    a, b = ins.split(",")
+    if "..." in a:                       # name the leading axes explicitly
+        lead = "ABCDEFGH"[:jnp.ndim(x) - len(a) + 3]
+        a, out = a.replace("...", lead), out.replace("...", lead)
+    batch = "".join(c for c in b if c in a and c in out)
+    a2 = batch + "".join(c for c in a if c not in batch)
+    o2 = batch + "".join(c for c in out if c not in batch)
+    y = jnp.einsum(f"{a2},{b}->{o2}",
+                   jnp.transpose(x, [a.index(c) for c in a2]), w,
+                   preferred_element_type=jnp.float32)
+    return jnp.transpose(y, [o2.index(c) for c in out])
+
+
 class KernelBackend:
     """Interface of a quantization kernel backend.
 
@@ -174,8 +199,7 @@ class KernelBackend:
         (int / zipml / levels / packed int4) and stacked (*S, K, N) weights.
         Backends may stream the codes instead of materializing the weight."""
         w = qt.decode(jnp.bfloat16)
-        return jnp.einsum(matmul_eq(jnp.ndim(x), w.ndim, transpose), x, w,
-                          preferred_element_type=jnp.float32)
+        return einsum_f32(matmul_eq(jnp.ndim(x), w.ndim, transpose), x, w)
 
     def quant_dense_out_q(self, x, qt, key, *, bits: int = 8,
                           out_dtype=None):

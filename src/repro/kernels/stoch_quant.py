@@ -41,8 +41,9 @@ def _sq_kernel(x_ref, rand_ref, scale_ref, codes_ref, *, s: int):
     x = x_ref[...].astype(jnp.float32)
     scale = scale_ref[...].astype(jnp.float32)          # (br, 1) row scales
     u = rand_ref[...]                                   # uint32
-    # uniform in [0,1): top 24 bits / 2^24 (exact in f32)
-    uf = (u >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    # uniform in [0,1): top 24 bits / 2^24 (exact in f32); Mosaic casts no
+    # unsigned int to float, so the shifted bits go through int32
+    uf = (u >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 24))
     mag = jnp.abs(x) / jnp.maximum(scale, 1e-30)
     t = jnp.clip(mag, 0.0, 1.0) * s
     lo = jnp.clip(jnp.floor(t), 0, s - 1)
@@ -86,8 +87,8 @@ def _ds_quant_kernel(x_ref, rand_ref, scale_ref, c1_ref, c2_ref, *, s: int):
     x = x_ref[...].astype(jnp.float32)
     scale = scale_ref[...].astype(jnp.float32)
     u = rand_ref[...]                                   # uint32
-    u1 = (u >> 16).astype(jnp.float32) * (1.0 / (1 << 16))
-    u2 = (u & 0xFFFF).astype(jnp.float32) * (1.0 / (1 << 16))
+    u1 = (u >> 16).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 16))
+    u2 = (u & 0xFFFF).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 16))
     mag = jnp.abs(x) / jnp.maximum(scale, 1e-30)
     t = jnp.clip(mag, 0.0, 1.0) * s
     base = jnp.clip(jnp.floor(t), 0, s - 1)             # shared base level
@@ -137,11 +138,19 @@ def ds_quant(x: jax.Array, rand: jax.Array, scale: jax.Array, *, s: int,
 
 
 def _absmax_kernel(x_ref, out_ref):
-    """Per-(row-block, col-block) absmax; the host wrapper reduces col blocks.
-    (Cross-step accumulation on a revisited out block is legal on TPU but not
-    honored by the CPU interpreter — per-block outputs keep both paths exact.)"""
-    out_ref[...] = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)),
-                           axis=1, keepdims=True)
+    """Row absmax accumulated across the column grid axis (the minor,
+    sequential one) into one revisited (br, 128) lane-dense block; every lane
+    of a row holds the same max."""
+    m = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)), axis=1, keepdims=True)
+    m = jnp.broadcast_to(m, out_ref.shape)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = m
+
+    @pl.when(pl.program_id(1) > 0)
+    def _acc():
+        out_ref[...] = jnp.maximum(out_ref[...], m)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -155,13 +164,12 @@ def row_absmax(x: jax.Array, *, block=None, interpret: bool | None = None):
     if c % bc:
         x = jnp.pad(x, ((0, 0), (0, bc - c % bc)))
         c = x.shape[1]
-    ncb = pl.cdiv(c, bc)
-    per_block = pl.pallas_call(
+    lanes = pl.pallas_call(
         _absmax_kernel,
-        grid=(pl.cdiv(r, br), ncb),
+        grid=(pl.cdiv(r, br), pl.cdiv(c, bc)),
         in_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((br, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, ncb), jnp.float32),
+        out_specs=pl.BlockSpec((br, 128), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, 128), jnp.float32),
         interpret=registry.resolve_interpret(interpret),
     )(x)
-    return jnp.max(per_block, axis=1, keepdims=True)
+    return lanes[:, :1]

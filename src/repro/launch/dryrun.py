@@ -202,10 +202,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     try:
         jfn, args = build_step(cfg, shape, mesh, opt_cfg=opt_cfg)
-        # jax < 0.5 has no sharding.set_mesh; Mesh is its own context manager
-        mesh_ctx = jax.sharding.set_mesh(mesh) \
-            if hasattr(jax.sharding, "set_mesh") else mesh
-        with mesh_ctx:
+        with jax.sharding.set_mesh(mesh):
             lowered = jfn.lower(*args)
             compiled = lowered.compile()
         res.compile_s = time.time() - t0
@@ -217,8 +214,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             alias = float(getattr(ma, "alias_size_in_bytes", 0))
             res.per_device_bytes = res.arg_bytes + res.out_bytes + res.temp_bytes - alias
         ca = compiled.cost_analysis()
-        if isinstance(ca, list):        # jax < 0.5 returns [dict]
-            ca = ca[0] if ca else None
         if ca:
             res.flops_per_device = float(ca.get("flops", 0.0))
             res.hbm_bytes_accessed = float(ca.get("bytes accessed", 0.0))

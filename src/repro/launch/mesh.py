@@ -6,11 +6,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax < 0.5 has no sharding.AxisType; Auto is its only behavior anyway
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the sharding rules are hints the compiler propagates
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -17,15 +17,22 @@ one batched full-precision step (output token-identical to vanilla):
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --reduced \
       --weight-bits 8 --weight-layout bitplane --spec-decode 3 --draft-bits 4
 
-Multi-replica mode (``--replicas N``) runs N engines — one paged pool and
-prefix cache each, data-parallel over the host's devices when several are
-visible (same placement policy as launch/sharding.py's data axis) — behind
-one shared submit queue (``--dispatch`` picks least-loaded, round-robin, or
-prefix-aware routing):
+Multi-replica mode (``--replicas N``) runs N engines — one paged pool,
+prefix cache and copy of the weights each, replica i on local device
+i mod ``--devices`` (default: every local device) — behind one shared submit
+queue (``--dispatch`` picks least-loaded, round-robin, or prefix-aware
+routing):
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --reduced \
       --requests 64 --replicas 4 --prefix-cache
+
+``--full`` serves the published configuration instead of the reduced one
+(gemma-2b: 2.5 B parameters, one TPU v5e per replica):
+
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --full \
+      --weight-bits 8 --kv-bits 8 --page-size 16 --chunk-pages 8 \
+      --replicas 4
 
 Legacy single-shot mode (the pre-engine fixed-batch greedy loop, kept as a
 compatibility wrapper around the ring-buffer cache):
@@ -46,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models import transformer as T
 from repro.precision.qat import quantize_param_tree
@@ -215,10 +223,10 @@ class ReplicaSet:
     pool, prefix cache (sharing is per-replica; the dispatcher's job is to
     keep a prefix family's requests landing on the same replica via the
     shared queue's FIFO order + least-loaded choice), and jit caches.
-    ``devices`` optionally pins replica i's arrays and dispatches to
-    ``devices[i % len(devices)]`` (``jax.default_device``), which is exactly
-    the data-parallel placement ``launch/sharding.py`` meshes give one
-    process per device group.
+    ``devices`` pins replica i to ``devices[i % len(devices)]``: its engine
+    is built and stepped under ``jax.default_device`` of that device, so its
+    pool and step inputs live there; the factory places the weights
+    (:func:`serve_engine` gives each replica its own copy on its device).
 
     Dispatch policies (all with bounded backlog — a replica whose
     in-flight-plus-pending count reaches ``2 × max_slots`` takes no more
@@ -280,10 +288,15 @@ class ReplicaSet:
         self.stats = {"rejected": 0, "migrated": 0, "deaths": 0,
                       "restarts": 0, "step_failures": 0}
 
+    def device_of(self, i: int):
+        """The device replica ``i`` is pinned to (None: the default one)."""
+        return None if self.devices is None \
+            else self.devices[i % len(self.devices)]
+
     def _device_ctx(self, i: int):
         if self.devices is None:
             return contextlib.nullcontext()
-        return jax.default_device(self.devices[i % len(self.devices)])
+        return jax.default_device(self.device_of(i))
 
     def submit(self, req) -> None:
         """Queue a request — or reject it up front (``ValueError`` +
@@ -539,7 +552,8 @@ def serve_engine(arch: str, *, reduced: bool = True, n_requests: int = 16,
     full-precision verify — token-identical output, needs
     ``weight_layout='bitplane'``); ``replicas > 1`` serves the trace through
     a :class:`ReplicaSet` (one engine per replica, shared queue; ``devices``
-    pins replicas round-robin; ``dispatch`` picks the routing policy —
+    pins replicas round-robin, each with its own copy of the weights on its
+    device; ``dispatch`` picks the routing policy —
     ``'prefix'`` routes prompt families to the replica owning their trie
     pages). Returns (engine-or-replicaset, results dict rid → Finished).
     Throughput/byte stats via ``engine.throughput()`` /
@@ -589,6 +603,10 @@ def serve_engine(arch: str, *, reduced: bool = True, n_requests: int = 16,
         if ship_dir is not None:
             from repro.ckpt import load_ship_weights
             p = load_ship_weights(ship_dir, bits=plan.model_bits or None)
+        if devices:
+            # the replica's own copy: a replica computing on weights that
+            # sit on another device would run there instead
+            p = jax.device_put(p, devices[i % len(devices)])
         return ServeEngine(p, cfg, plan=plan, max_slots=max_slots,
                            page_size=page_size, max_seq_len=max_seq_len,
                            backend=backend, autoscaler=mk_autoscaler(),
@@ -615,6 +633,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.ARCH_IDS)
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="the published configuration, not the reduced one")
     ap.add_argument("--kv-bits", type=int, default=0, choices=(0, 4, 8))
     ap.add_argument("--weight-bits", type=int, default=0)
     ap.add_argument("--optimal-levels", action="store_true")
@@ -634,6 +654,9 @@ def main(argv=None):
                          "(implies interleaved prefill/decode)")
     ap.add_argument("--replicas", type=int, default=1,
                     help="engine replicas behind a shared submit queue")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="spread replicas over the first N local devices, "
+                         "replica i on device i mod N (default: all)")
     ap.add_argument("--dispatch", default="least_loaded",
                     choices=("least_loaded", "round_robin", "prefix"),
                     help="replica routing: prefix = route prompt families "
@@ -661,6 +684,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args(argv)
+    print(f"[serve] compile cache: {compile_cache.enable()}")
 
     if args.legacy:
         tokens, tps = serve(args.arch, reduced=args.reduced, batch=args.batch,
@@ -683,6 +707,8 @@ def main(argv=None):
         autoscale=args.autoscale, slo_admit_ms=args.slo_admit_ms,
         prefix_cache=args.prefix_cache, chunk_pages=args.chunk_pages,
         replicas=args.replicas, dispatch=args.dispatch,
+        devices=jax.local_devices()[:args.devices] if args.replicas > 1
+        else None,
         spec_decode=args.spec_decode, draft_bits=args.draft_bits)
     gen_total = sum(f.n_generated for f in results.values())
     if isinstance(engine, ReplicaSet):

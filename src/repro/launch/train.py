@@ -17,6 +17,7 @@ import warnings
 
 from repro import configs
 from repro.kernels import registry
+from repro.launch import compile_cache
 from repro.data.pipeline import TokenStreamConfig
 from repro.quant import PrecisionPlan
 from repro.optim import adamw
@@ -114,6 +115,7 @@ def main(argv=None):
                     help="quantization kernel backend (default: "
                          "$ZIPML_KERNEL_BACKEND or per jax.default_backend())")
     args = ap.parse_args(argv)
+    print(f"[train] compile cache: {compile_cache.enable()}")
     precision = PrecisionPlan(model_bits=args.weight_bits,
                               model_storage=args.weight_storage,
                               grad_bits=args.grad_bits)

@@ -170,21 +170,18 @@ def shard_hint(x: jax.Array, spec) -> jax.Array:
     Model code calls this at activation boundaries; the launcher's mesh context
     makes it bind. ``spec`` is a PartitionSpec.
     """
-    try:
-        env_mesh = jax.sharding.get_abstract_mesh()  # jax>=0.4.35
-        if env_mesh is None or not env_mesh.shape:
-            return x
-        # only apply when every named axis in the spec exists in the mesh
-        names = set()
-        for part in spec:
-            if part is None:
-                continue
-            parts = part if isinstance(part, (tuple, list)) else (part,)
-            names.update(parts)
-        if not names <= set(env_mesh.shape.keys()):
-            return x
-        # drop axes whose size does not divide the dim? leave to caller; jax
-        # raises a clear error which the dry-run surfaces as a config bug.
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    env_mesh = jax.sharding.get_abstract_mesh()
+    if env_mesh is None or not env_mesh.shape:
         return x
+    # only apply when every named axis in the spec exists in the mesh
+    names = set()
+    for part in spec:
+        if part is None:
+            continue
+        parts = part if isinstance(part, (tuple, list)) else (part,)
+        names.update(parts)
+    if not names <= set(env_mesh.shape.keys()):
+        return x
+    # an axis whose size does not divide the dim raises a clear error, which
+    # the dry-run surfaces as a config bug
+    return jax.lax.with_sharding_constraint(x, spec)

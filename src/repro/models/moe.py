@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.registry import einsum_f32
 from repro.quant import QTensor, ShipWeight, quant_dense
 
 from .layers import _SPLICE_ERROR, Params, init_dense, shard_hint
@@ -35,7 +36,7 @@ from .layers import _SPLICE_ERROR, Params, init_dense, shard_hint
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _gexpert_einsum(eq: str, x, w):
-    return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32)
+    return einsum_f32(eq, x, w)
 
 
 def _ge_fwd(eq, x, w):
@@ -54,8 +55,7 @@ def _ge_bwd(eq, res, g):
     in1, in2_arrow = eq.split(",")
     in2, out = in2_arrow.split("->")
     g = g.astype(x.dtype)
-    dx = jnp.einsum(f"{out},{in2}->{in1}", g, w,
-                    preferred_element_type=jnp.float32).astype(x.dtype)
+    dx = einsum_f32(f"{out},{in2}->{in1}", g, w).astype(x.dtype)
     # bf16 straight out of the einsum: the cross-device psum of the sharded
     # contraction happens on the einsum OUTPUT — a later astype would ride
     # after the all-reduce and save nothing
@@ -80,7 +80,7 @@ def _qeinsum(eq: str, x: jax.Array, sub: Params) -> jax.Array:
     w = sub["w"]
     if isinstance(w, (QTensor, ShipWeight)):
         return quant_dense(x, w)
-    return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32)
+    return einsum_f32(eq, x, w)
 
 
 def _gq_einsum(eq: str, x: jax.Array, sub: Params) -> jax.Array:
@@ -226,10 +226,7 @@ def moe_dispatch_grouped(p: Params, xg: jax.Array, spec: MoESpec) -> jax.Array:
 
 
 def _mesh_axis_sizes(axes: tuple) -> int | None:
-    try:
-        am = jax.sharding.get_abstract_mesh()   # jax>=0.4.35 only
-    except AttributeError:
-        return None
+    am = jax.sharding.get_abstract_mesh()
     if am is None or not am.shape:
         return None
     sizes = dict(am.shape)

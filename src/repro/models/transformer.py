@@ -269,24 +269,6 @@ def _stack_trees(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
-@jax.custom_vjp
-def _fwd_barrier(x):
-    """optimization_barrier on the forward pass only; identity for gradients
-    (jax<0.5 has no differentiation rule for the barrier primitive)."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _fwd_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _fwd_barrier_bwd(_, g):
-    return (g,)
-
-
-_fwd_barrier.defvjp(_fwd_barrier_fwd, _fwd_barrier_bwd)
-
-
 def _scan_layers(cfg: ModelConfig, stacked: Params, x: jax.Array) -> jax.Array:
     body = lambda carry, layer: (_layer_fwd(cfg, layer, carry), None)
     if cfg.remat:
@@ -296,7 +278,7 @@ def _scan_layers(cfg: ModelConfig, stacked: Params, x: jax.Array) -> jax.Array:
             out, _ = inner(carry, layer)
             # barrier outside the checkpoint: stops XLA hoisting the bwd's
             # bf16→f32 convert into the fwd save (doubles stacked-carry memory)
-            return _fwd_barrier(out), None
+            return jax.lax.optimization_barrier(out), None
     if not cfg.scan_layers:
         n = jax.tree.leaves(stacked)[0].shape[0]
         for layer in _unstack(stacked, n):

@@ -66,11 +66,11 @@ SPACES = {
         {"bm": 128, "bk": 512},
         {"bm": 128, "bk": 256},
     ],
-    "qmm_bitplane": [
-        {"bm": 256, "bk": 512, "bn": 256},
-        {"bm": 128, "bk": 512, "bn": 256},
-        {"bm": 256, "bk": 256, "bn": 256},
-        {"bm": 128, "bk": 256, "bn": 128},
+    "qmm_bitplane": [                    # bn/32 words fill the 128 lanes
+        {"bm": 128, "bk": 256, "bn": 4096},
+        {"bm": 256, "bk": 256, "bn": 4096},
+        {"bm": 128, "bk": 512, "bn": 4096},
+        {"bm": 256, "bk": 128, "bn": 4096},
     ],
     "ds_quant": [
         {"br": 256, "bc": 512},

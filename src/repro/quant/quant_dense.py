@@ -166,8 +166,8 @@ def quant_dense(x: jax.Array, w, *, transpose: bool = False,
     if isinstance(w, QTensor):
         return _qd(x, w, backend, transpose)
     reg = _registry()
-    return jnp.einsum(reg.matmul_eq(jnp.ndim(x), jnp.ndim(w), transpose),
-                      x, w, preferred_element_type=jnp.float32)
+    return reg.einsum_f32(reg.matmul_eq(jnp.ndim(x), jnp.ndim(w), transpose),
+                          x, w)
 
 
 def quant_dense_q(x: jax.Array, w, key: jax.Array, *, bits: int = 8,
@@ -186,7 +186,7 @@ def quant_dense_q(x: jax.Array, w, key: jax.Array, *, bits: int = 8,
     from .scheme import QScheme
 
     reg = _registry()
-    y = jnp.einsum(reg.matmul_eq(jnp.ndim(x), jnp.ndim(w), False), x, w,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
+    y = reg.einsum_f32(reg.matmul_eq(jnp.ndim(x), jnp.ndim(w), False), x,
+                       w).astype(x.dtype)
     return ds_pair(y, QScheme.int_symmetric(bits, scaling="row",
                                             rounding="ds"), key)

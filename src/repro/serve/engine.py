@@ -288,6 +288,7 @@ class ServeEngine:
         # two decode variants: the greedy-only one skips the sort +
         # categorical machinery entirely (the common case); lazily compiled
         self._decode_jits: dict[bool, Any] = {}
+        self._logits_jits: dict[str | None, Any] = {}
         self._prefill_jits: dict[int, Any] = {}
         self._chunk_jit_fn = None
         self._draft_jit_fn = None
@@ -297,15 +298,14 @@ class ServeEngine:
                 lg[None], t[None], k[None], key[None])[0])
 
     # ------------------------------------------------------------ device fns
-    def _make_decode_fn(self, sampled: bool):
-        """``sampled=False`` compiles the greedy-only fast path (no vocab
-        sort, no categorical) — picked per step from host state."""
+    def _make_decode_forward(self, kb):
+        """One paged decode step's forward under kernel backend ``kb``:
+        append each slot's token KV, attend, and return the (B, V) logits
+        with the pool as it would stand after the step."""
         cfg, spec = self.cfg, self.cfg.attn_spec
         page = self.page_size
-        kb = registry.get(self.backend)
 
-        def decode_fn(params, pool, tokens, positions, block_table, active,
-                      base_keys, temps, topks):
+        def forward(params, pool, tokens, positions, block_table, active):
             b = tokens.shape[0]
             pos = positions.astype(jnp.int32)
             x = embed(params["embed"], tokens).astype(cfg.dtype)      # (B,1,d)
@@ -337,10 +337,22 @@ class ServeEngine:
             xs = (params["layers"], pool.k_pages, pool.v_pages,
                   pool.k_scale, pool.v_scale)
             x, planes = jax.lax.scan(body, x, xs)
-            new_pool = pg.PagedKVPool(*planes)
-            logits = T.final_logits(params, cfg, x)[:, 0]             # (B, V)
+            return T.final_logits(params, cfg, x)[:, 0], pg.PagedKVPool(*planes)
+
+        return forward
+
+    def _make_decode_fn(self, sampled: bool):
+        """``sampled=False`` compiles the greedy-only fast path (no vocab
+        sort, no categorical) — picked per step from host state."""
+        forward = self._make_decode_forward(registry.get(self.backend))
+
+        def decode_fn(params, pool, tokens, positions, block_table, active,
+                      base_keys, temps, topks):
+            logits, new_pool = forward(params, pool, tokens, positions,
+                                       block_table, active)
             if sampled:
-                keys = jax.vmap(sampling.slot_key)(base_keys, pos + 1)
+                keys = jax.vmap(sampling.slot_key)(
+                    base_keys, positions.astype(jnp.int32) + 1)
                 tok = sampling.sample_tokens(logits, temps, topks, keys)
             else:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -351,6 +363,24 @@ class ServeEngine:
             return jnp.where(active, tok, 0), ok, new_pool
 
         return decode_fn
+
+    def decode_logits(self, backend: str | None = None) -> jax.Array:
+        """(max_slots, V) logits the next decode step would sample from,
+        computed on the current state without advancing it — a numerics
+        probe that lets two kernel backends be compared on one live state.
+        ``backend`` (default: the engine's) runs every op of the step, the
+        weight matmuls included."""
+        backend = backend or self.backend
+        fn = self._logits_jits.get(backend)
+        if fn is None:
+            fn = self._logits_jits[backend] = jax.jit(
+                self._make_decode_forward(registry.get(backend)))
+        with registry.using(backend):                 # traced on first call
+            logits, _ = fn(self.params, self.pool,
+                           jnp.asarray(self._last_tok)[:, None],
+                           jnp.asarray(self._lens), jnp.asarray(self._bt),
+                           jnp.asarray(self._active))
+        return logits
 
     def _decode_jit(self, sampled: bool):
         fn = self._decode_jits.get(sampled)

@@ -19,22 +19,24 @@ What they pin:
     decoding on under prefix-aware dispatch, token-identical to the
     non-speculative replica set.
 """
+import os
+import subprocess
+import sys
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import quant
 from repro.precision import gradcomp
 from repro.quant import QScheme
 
-if jax.device_count() < 8:
-    pytest.skip(
-        "needs 8 devices — run under "
-        "XLA_FLAGS=--xla_force_host_platform_device_count=8",
-        allow_module_level=True)
+needs8 = pytest.mark.skipif(
+    jax.device_count() < 8,
+    reason="needs 8 devices — run under "
+           "XLA_FLAGS=--xla_force_host_platform_device_count=8")
 
 KEY = jax.random.PRNGKey(0)
 
@@ -43,6 +45,7 @@ def _mesh(axis="data"):
     return Mesh(np.array(jax.devices()[:8]), (axis,))
 
 
+@needs8
 class TestQTensorSharding:
     def test_code_plane_sharding_survives_shard_map(self):
         """Shard a QTensor's codes 8-way, map a decode over the shards: the
@@ -58,8 +61,8 @@ class TestQTensorSharding:
         shards = {s.device for s in qt_sharded.codes.addressable_shards}
         assert len(shards) == 8
 
-        f = shard_map(lambda q: q.decode(), mesh=mesh, in_specs=(qt_spec,),
-                      out_specs=P("data", None), check_rep=False)
+        f = jax.shard_map(lambda q: q.decode(), mesh=mesh, in_specs=(qt_spec,),
+                      out_specs=P("data", None), check_vma=False)
         out = jax.jit(f)(qt_sharded)
         assert out.sharding.spec == P("data", None)
         np.testing.assert_allclose(np.asarray(out), np.asarray(qt.decode()),
@@ -76,15 +79,16 @@ class TestQTensorSharding:
         qs = jax.device_put(qt, jax.tree.map(
             lambda s: NamedSharding(mesh, s), spec,
             is_leaf=lambda s: isinstance(s, P)))
-        f = shard_map(lambda q: (q.decode() + q.decode2()) / 2, mesh=mesh,
+        f = jax.shard_map(lambda q: (q.decode() + q.decode2()) / 2, mesh=mesh,
                       in_specs=(spec,), out_specs=P("data", None),
-                      check_rep=False)
+                      check_vma=False)
         out = jax.jit(f)(qs)
         np.testing.assert_allclose(
             np.asarray(out),
             np.asarray((qt.decode() + qt.decode2()) / 2), rtol=1e-6)
 
 
+@needs8
 class TestBitplaneSharding:
     def test_bitplane_code_planes_shard_over_rows(self):
         """A bit-plane weight shards 8-way over its contraction (row) axis —
@@ -104,8 +108,8 @@ class TestBitplaneSharding:
             is_leaf=lambda s: isinstance(s, P)))
         assert len({s.device for s in qs.codes.addressable_shards}) == 8
 
-        f = shard_map(lambda q: q.decode(), mesh=mesh, in_specs=(spec,),
-                      out_specs=P("data", None), check_rep=False)
+        f = jax.shard_map(lambda q: q.decode(), mesh=mesh, in_specs=(spec,),
+                      out_specs=P("data", None), check_vma=False)
         out = jax.jit(f)(qs)
         assert out.sharding.spec in (P("data"), P("data", None))
         np.testing.assert_allclose(np.asarray(out), np.asarray(qt.decode()),
@@ -125,6 +129,7 @@ class TestBitplaneSharding:
                     rtol=5e-3 if be == "ref" else 1e-5)
 
 
+@needs8
 class TestPackedQuantDense:
     def test_packed_int4_code_plane_shards_and_matmuls(self):
         """A nibble-packed int4 weight plane shards 8-way over its (even)
@@ -155,6 +160,7 @@ class TestPackedQuantDense:
                     rtol=5e-3 if be == "ref" else 1e-5)
 
 
+@needs8
 class TestCompressedPsum:
     def test_mean_of_quantized_members_8way(self):
         """The C3 compressed all-reduce over a real 8-member axis equals the
@@ -171,8 +177,8 @@ class TestCompressedPsum:
             idx = jax.lax.axis_index("pod")
             return psum({"g": g_slice[0]}, jax.random.fold_in(key, idx))
 
-        f = shard_map(member, mesh=mesh, in_specs=(P("pod", None), P()),
-                      out_specs=P(), check_rep=False)
+        f = jax.shard_map(member, mesh=mesh, in_specs=(P("pod", None), P()),
+                      out_specs=P(), check_vma=False)
         out = np.asarray(jax.jit(f)(per_member, KEY)["g"])
 
         # oracle: quantize each member with its folded key, average
@@ -186,6 +192,7 @@ class TestCompressedPsum:
         assert np.max(np.abs(out - true_mean)) <= step + 1e-5
 
 
+@needs8
 class TestShardedServe:
     def _op_args(self, kv_bits):
         rng = np.random.default_rng(1)
@@ -326,3 +333,37 @@ class TestShardedServe:
         for eng in rs.engines:
             eng.release_prefix_cache()
             eng.allocator.check_leaks(0)
+
+
+# one process with four forced host devices, whatever this process has
+_PLACEMENT = """
+import jax
+from repro.launch.serve import serve_engine
+
+devices = jax.devices()
+assert len(devices) == 4, devices
+rs, results = serve_engine(
+    "gemma-2b", n_requests=8, max_new=4, kv_bits=8, weight_bits=8,
+    page_size=8, max_slots=2, replicas=4, devices=devices)
+assert sorted(results) == list(range(8)), sorted(results)
+for i, eng in enumerate(rs.engines):
+    want = {rs.device_of(i)}
+    for leaf in jax.tree.leaves((eng.params, eng.pool)):
+        assert leaf.devices() == want, (i, leaf.devices(), want)
+    assert rs.dispatched[i] >= 1, rs.dispatched
+assert len({rs.device_of(i) for i in range(4)}) == 4
+assert rs.stats["deaths"] == rs.stats["step_failures"] == 0, rs.stats
+print("placement ok")
+"""
+
+
+def test_replicas_hold_weights_and_pool_on_their_own_device():
+    """Four replicas on four forced host devices: each replica's weights
+    and KV pool sit on its own device (not device 0's copy), and every
+    replica serves."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "placement ok" in out.stdout

@@ -14,10 +14,8 @@ Each property is the load-bearing guarantee of a subsystem:
 import numpy as np
 import jax
 import jax.numpy as jnp
-import pytest
 
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 import repro.core.quantize as qz
 from repro import quant

@@ -5,7 +5,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import quant
@@ -267,8 +266,8 @@ class TestShardMap:
         psum = gradcomp.make_compressed_psum("pod", 8)
         g = {"a": jax.random.normal(KEY, (8, 4)),
              "b": jax.random.normal(jax.random.fold_in(KEY, 1), (16,))}
-        f = shard_map(psum, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(psum, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                      check_vma=False)
         out = f(g, KEY)
         # single member ⇒ the mean equals the dequantized compression of g
         comp, _ = gradcomp.compress_tree(g, 8, KEY)

@@ -82,8 +82,10 @@ class TestRefBitExact:
         assert qt.scale.shape == (3, 1, 8)     # per-expert channel scales
         x = jax.random.normal(KEY, (5, 3, 7, 16)).astype(jnp.bfloat16)
         got = quant_dense(x, qt, backend="ref")
-        want = jnp.einsum("gecd,edf->gecf", x, qt.decode(jnp.bfloat16),
-                          preferred_element_type=jnp.float32)
+        w = qt.decode(jnp.bfloat16)
+        want = jnp.stack([jnp.einsum("gcd,df->gcf", x[:, e], w[e],
+                                     preferred_element_type=jnp.float32)
+                          for e in range(w.shape[0])], axis=1)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_unembed_transpose(self):

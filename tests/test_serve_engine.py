@@ -203,6 +203,30 @@ class TestEngineParity:
         np.testing.assert_array_equal(runs["pallas"][:, :2],
                                       runs["ref"][:, :2])
 
+    def test_decode_logits_probe_leaves_state_and_predicts_next_token(self):
+        """decode_logits reads the logits the next decode step samples from
+        without advancing the engine: its argmax is the token the next
+        greedy step emits, and a second backend probes the same state."""
+        cfg = _cfg()
+        eng = ServeEngine(_params(cfg), cfg, plan=PrecisionPlan(kv_bits=8),
+                          max_slots=2, page_size=8, max_seq_len=32)
+        for i in range(2):
+            eng.submit(Request(rid=i, prompt=np.arange(5 + i) % cfg.vocab_size,
+                               max_new_tokens=6))
+        while eng.n_active < 2:
+            eng.step()
+        lens = eng._lens.copy()
+        logits = np.asarray(eng.decode_logits())
+        other = np.asarray(eng.decode_logits("pallas"))
+        np.testing.assert_array_equal(eng._lens, lens)
+        # bf16 rounding apart (ref rounds attention probabilities to bf16)
+        rms = np.sqrt(np.mean((other - logits) ** 2) / np.mean(logits ** 2))
+        assert rms < 0.1, rms
+        want = logits.argmax(-1)
+        eng.step()
+        got = [eng._slots[i]["gen"][-1] for i in range(2)]
+        np.testing.assert_array_equal(got, want)
+
 
 class TestSchedulerInvariants:
     def _mixed_requests(self, cfg, n, seed=0, **kw):

@@ -43,11 +43,8 @@ class TestCheckpoint:
         mgr = CheckpointManager(str(tmp_path))
         tree = {"w": jnp.arange(64.0).reshape(8, 8)}
         mgr.save(3, tree, blocking=True)
-        if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5: explicit axis types
-            mesh = jax.make_mesh((1,), ("data",),
-                                 axis_types=(jax.sharding.AxisType.Auto,))
-        else:
-            mesh = jax.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         shardings = {"w": jax.sharding.NamedSharding(
             mesh, jax.sharding.PartitionSpec("data", None))}
         got, _ = mgr.restore(jax.eval_shape(lambda: tree), shardings=shardings)
