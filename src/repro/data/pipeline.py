@@ -18,6 +18,7 @@ from typing import Iterator
 
 import numpy as np
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,7 +79,8 @@ class TokenStream:
             yield self.next_batch()
 
     def next_batch(self) -> dict:
-        batch = self._batch_at(self.cursor.step)
+        with TraceAnnotation("data.next_batch"):
+            batch = self._batch_at(self.cursor.step)
         self.cursor = Cursor(self.cursor.step + 1, self.cursor.epoch)
         return batch
 

@@ -629,6 +629,29 @@ def serve_engine(arch: str, *, reduced: bool = True, n_requests: int = 16,
     return engine, results
 
 
+def latency_line(results: dict) -> str:
+    """Time to first token (first commit − submit) and the gaps between a
+    request's successive tokens, p50/p95 with their counts, from the
+    per-request times in ``Finished``."""
+    ttft, gaps = [], []
+    for f in results.values():
+        t = f.token_times
+        if t.size:
+            ttft.append(t[0] - f.t_submit)
+            gaps.extend(np.diff(t))
+
+    def pct(xs):
+        xs = np.asarray(xs, np.float64)
+        xs = xs[np.isfinite(xs)]
+        if not xs.size:
+            return "n=0"
+        p50, p95 = np.percentile(xs, [50, 95]) * 1e3
+        return f"p50 {p50:.1f} ms, p95 {p95:.1f} ms (n={xs.size})"
+
+    return (f"[serve-engine] time to first token {pct(ttft)}; "
+            f"token gaps {pct(gaps)}")
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.ARCH_IDS)
@@ -716,6 +739,7 @@ def main(argv=None):
         print(f"[serve-engine] {len(results)} requests across "
               f"{len(rs.engines)} replicas, {gen_total} tokens generated "
               f"(dispatch={rs.dispatched})")
+        print(latency_line(results))
         print(f"[serve-engine] aggregate steady-state decode: "
               f"{rs.throughput():.1f} tok/s; "
               f"preemptions={rs.stats_sum('preemptions')}")
@@ -750,6 +774,7 @@ def main(argv=None):
     print(f"[serve-engine] {len(results)} requests, {gen_total} tokens "
           f"generated in {st['decode_steps']} decode steps "
           f"(+{st['prefill_tokens']} prefill tokens)")
+    print(latency_line(results))
     print(f"[serve-engine] steady-state decode: {engine.throughput():.1f} "
           f"tok/s; preemptions={st['preemptions']}")
     if args.spec_decode:

@@ -117,14 +117,25 @@ path; shared prefix pages are never written after sharing.
 Throughput accounting deliberately excludes the first decode call (jit
 compile) — ``stats['decode_seconds']`` is steady-state only, the fix the
 old serve loop needed (its t0 sat before compilation). All scheduler timing
-goes through the injectable ``clock`` (``admit_waits`` *and*
-``decode_times``), so virtual-clock replays never mix real and virtual
+goes through the injectable ``clock`` (``admit_waits``, ``decode_times`` and
+each request's submit, admission and per-token commit times in
+:class:`Finished`), so virtual-clock replays never mix real and virtual
 time.
+
+Tracing: each scheduler phase runs in a host span (``serve.step`` holding
+``serve.admit``, ``serve.prefill_chunk``, ``serve.first_token``,
+``serve.decode.dispatch``, ``serve.decode.wait``, ``serve.commit`` and
+``serve.spec``; ``serve.page_checksum`` around the prefix cache's page read),
+and each jitted program has a stable name (``serve_decode``, ``serve_chunk``,
+``serve_prefill``, ``serve_draft``, ``serve_verify``, ``serve_sample_first``,
+``serve_logits``), so a profiler trace attributes host and device time to
+them. A span costs under a microsecond while no trace is being taken.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 import time
 import zlib
 from typing import Any
@@ -132,6 +143,7 @@ from typing import Any
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation as span
 
 from repro.kernels import registry
 from repro.kernels.ops import kv_bits_of
@@ -163,11 +175,44 @@ class Request:
 
 @dataclasses.dataclass(frozen=True)
 class Finished:
+    """A request's outcome. Times are on the engine's clock: ``t_submit``
+    when it was queued, ``t_admit`` when it first took a slot, and
+    ``token_times[i]`` when generated token ``i`` was first committed (a
+    token replayed after preemption or migration keeps the time the user
+    first saw it; NaN where no time was recorded)."""
+
     rid: int
     tokens: np.ndarray               # prompt + generated, 1-D int32
     prompt_len: int
     n_generated: int
     reason: str                      # 'eos' | 'length' | 'nan' | 'retries'
+    t_submit: float = math.nan
+    t_admit: float = math.nan
+    token_times: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros((0,), np.float64))
+
+
+def _finished(src: dict, tokens: np.ndarray, n_generated: int,
+              reason: str) -> Finished:
+    """A Finished from a slot's state or a queue entry (both carry the
+    request, its prompt, ``t_submit`` and the commit ``times``)."""
+    times = np.full((n_generated,), np.nan)
+    seen = src.get("times", ())[:n_generated]
+    times[:len(seen)] = seen
+    t_admit = src.get("t_admit")
+    return Finished(rid=src["req"].rid, tokens=tokens,
+                    prompt_len=len(src["prompt"]), n_generated=n_generated,
+                    reason=reason, t_submit=src["t_submit"],
+                    t_admit=math.nan if t_admit is None else t_admit,
+                    token_times=times)
+
+
+def _commit(state: dict, tok: int, now: float) -> None:
+    """Append a generated token; a replayed token keeps its first commit
+    time, a new one is stamped ``now``."""
+    state["gen"].append(tok)
+    if len(state["times"]) < len(state["gen"]):
+        state["times"].append(now)
 
 
 class ServeEngine:
@@ -236,8 +281,8 @@ class ServeEngine:
         self.stats = {"admitted": 0, "finished": 0, "preemptions": 0,
                       "decode_steps": 0, "decode_tokens": 0,
                       "decode_seconds": 0.0, "steady_decode_tokens": 0,
-                      "prefill_tokens": 0, "admit_wait_seconds": 0.0,
-                      "prefill_chunks": 0, "max_prefill_tokens_per_step": 0,
+                      "prefill_tokens": 0, "prefill_chunks": 0,
+                      "max_prefill_tokens_per_step": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefix_hit_tokens": 0, "spec_steps": 0,
                       "spec_draft_tokens": 0, "spec_accepted_tokens": 0,
@@ -293,9 +338,12 @@ class ServeEngine:
         self._chunk_jit_fn = None
         self._draft_jit_fn = None
         self._verify_jits: dict[bool, Any] = {}
-        self._sample1 = jax.jit(
-            lambda lg, t, k, key: sampling.sample_tokens(
-                lg[None], t[None], k[None], key[None])[0])
+
+        def serve_sample_first(lg, t, k, key):
+            return sampling.sample_tokens(lg[None], t[None], k[None],
+                                          key[None])[0]
+
+        self._sample1 = jax.jit(serve_sample_first)
 
     # ------------------------------------------------------------ device fns
     def _make_decode_forward(self, kb):
@@ -346,8 +394,8 @@ class ServeEngine:
         sort, no categorical) — picked per step from host state."""
         forward = self._make_decode_forward(registry.get(self.backend))
 
-        def decode_fn(params, pool, tokens, positions, block_table, active,
-                      base_keys, temps, topks):
+        def serve_decode(params, pool, tokens, positions, block_table,
+                         active, base_keys, temps, topks):
             logits, new_pool = forward(params, pool, tokens, positions,
                                        block_table, active)
             if sampled:
@@ -362,7 +410,7 @@ class ServeEngine:
             ok = jnp.isfinite(logits).all(axis=-1)
             return jnp.where(active, tok, 0), ok, new_pool
 
-        return decode_fn
+        return serve_decode
 
     def decode_logits(self, backend: str | None = None) -> jax.Array:
         """(max_slots, V) logits the next decode step would sample from,
@@ -373,8 +421,12 @@ class ServeEngine:
         backend = backend or self.backend
         fn = self._logits_jits.get(backend)
         if fn is None:
-            fn = self._logits_jits[backend] = jax.jit(
-                self._make_decode_forward(registry.get(backend)))
+            forward = self._make_decode_forward(registry.get(backend))
+
+            def serve_logits(*args):
+                return forward(*args)
+
+            fn = self._logits_jits[backend] = jax.jit(serve_logits)
         with registry.using(backend):                 # traced on first call
             logits, _ = fn(self.params, self.pool,
                            jnp.asarray(self._last_tok)[:, None],
@@ -399,14 +451,14 @@ class ServeEngine:
         if fn is None:
             cfg_fp = self._cfg_fp
 
-            def prefill_fn(params, toks, last_pos, page_ids, pool):
+            def serve_prefill(params, toks, last_pos, page_ids, pool):
                 logits, state = T.prefill(params, toks, cfg_fp,
                                           last_pos=last_pos)
                 k_all = state.layers.k[:, 0]       # (L, bucket, Hkv, D)
                 v_all = state.layers.v[:, 0]
                 return logits[0], pg.write_prompt(pool, k_all, v_all, page_ids)
 
-            fn = self._prefill_jits[bucket] = jax.jit(prefill_fn)
+            fn = self._prefill_jits[bucket] = jax.jit(serve_prefill)
         return fn
 
     def _make_chunk_fn(self):
@@ -434,8 +486,8 @@ class ServeEngine:
         n_ctx = self.max_pages_per_seq * page
         g, d = spec.n_kv_heads, spec.head_dim
 
-        def chunk_fn(params, pool, toks, pos0, page_ids, true_len, bt_row,
-                     last_rel):
+        def serve_chunk(params, pool, toks, pos0, page_ids, true_len, bt_row,
+                        last_rel):
             positions = pos0 + jnp.arange(C, dtype=jnp.int32)         # (C,)
             h = embed(params["embed"], toks[None]).astype(cfg.dtype)  # (1,C,d)
             key_pos = jnp.arange(n_ctx, dtype=jnp.int32)
@@ -486,7 +538,7 @@ class ServeEngine:
             logits = T.final_logits(params, cfg, h)[0]                # (C, V)
             return logits[last_rel], new_pool
 
-        return chunk_fn
+        return serve_chunk
 
     def _chunk_jit(self):
         if self._chunk_jit_fn is None:
@@ -505,8 +557,8 @@ class ServeEngine:
         decode_fn = self._make_decode_fn(sampled=False)
         k = self.spec_decode
 
-        def draft_fn(params, pool, last_tok, lens, block_table, active,
-                     base_keys, temps, topks):
+        def serve_draft(params, pool, last_tok, lens, block_table, active,
+                        base_keys, temps, topks):
             def body(carry, _):
                 pool, tok, lens = carry
                 nxt, _, pool = decode_fn(params, pool, tok[:, None], lens,
@@ -518,7 +570,7 @@ class ServeEngine:
                 body, (pool, last_tok, lens), None, length=k)
             return jnp.moveaxis(toks, 0, 1), pool              # (B, k)
 
-        return draft_fn
+        return serve_draft
 
     def _draft_jit(self):
         if self._draft_jit_fn is None:
@@ -550,8 +602,8 @@ class ServeEngine:
         n_ctx = self.max_pages_per_seq * page
         g, d = spec.n_kv_heads, spec.head_dim
 
-        def verify_fn(params, pool, draft, last_tok, lens, block_table,
-                      active, base_keys, temps, topks):
+        def serve_verify(params, pool, draft, last_tok, lens, block_table,
+                         active, base_keys, temps, topks):
             b = last_tok.shape[0]
             toks = jnp.concatenate([last_tok[:, None], draft], axis=1)
             positions = lens[:, None] + jnp.arange(W, dtype=jnp.int32)
@@ -612,7 +664,7 @@ class ServeEngine:
             ok = jnp.isfinite(logits).all(axis=(1, 2))
             return jnp.where(active[:, None], tgt, 0), ok, new_pool
 
-        return verify_fn
+        return serve_verify
 
     def _verify_jit(self, sampled: bool):
         fn = self._verify_jits.get(sampled)
@@ -648,14 +700,16 @@ class ServeEngine:
             raise ValueError(f"request {req.rid} can never fit: {why}")
         self._queue.append({"req": req, "prompt": prompt,
                             "replay": np.zeros((0,), np.int32),
-                            "t_submit": self._clock(), "retries": 0})
+                            "t_submit": self._clock(), "retries": 0,
+                            "times": []})
 
     def submit_entry(self, entry: dict) -> None:
         """Queue a prepared entry — the migration path: a request harvested
         off a dead replica re-enters here with its original ``t_submit``
         (the admission-latency signal keeps accruing across the failure),
-        its committed tokens as the replay list (bit-exact recompute), and
-        its retry count (the budget is per-request, not per-replica)."""
+        its committed tokens as the replay list (bit-exact recompute) with
+        their first commit times and its first admission time, and its
+        retry count (the budget is per-request, not per-replica)."""
         self._queue.append({
             "req": entry["req"],
             "prompt": np.asarray(entry["prompt"], np.int32).reshape(-1),
@@ -663,6 +717,8 @@ class ServeEngine:
                                            np.zeros((0,), np.int32)),
                                  np.int32).reshape(-1),
             "t_submit": entry["t_submit"],
+            "t_admit": entry.get("t_admit"),
+            "times": list(entry.get("times", ())),
             "retries": int(entry.get("retries", 0))})
 
     def harvest(self) -> list[dict]:
@@ -684,6 +740,7 @@ class ServeEngine:
                 np.asarray(st["replay_left"], np.int32)])
             entries.append({"req": st["req"], "prompt": st["prompt"],
                             "replay": replay, "t_submit": st["t_submit"],
+                            "t_admit": st["t_admit"], "times": st["times"],
                             "retries": int(st.get("retries", 0)) + 1})
         for e in self._queue:
             entries.append({**e, "retries": int(e.get("retries", 0)) + 1})
@@ -855,9 +912,8 @@ class ServeEngine:
                     self.allocator.free(shared)    # hand the refs back
                 return                             # FIFO head-of-line wait
             self._queue.popleft()
-            wait = max(0.0, self._clock() - entry["t_submit"])
-            self.stats["admit_wait_seconds"] += wait
-            self.admit_waits.append(wait)
+            now = self._clock()
+            self.admit_waits.append(max(0.0, now - entry["t_submit"]))
             req = entry["req"]
             all_ids = shared + ids
             row = np.zeros((self.max_pages_per_seq,), np.int32)
@@ -874,10 +930,13 @@ class ServeEngine:
                     self.stats["prefix_hit_tokens"] += m * self.page_size
                 else:
                     self.stats["prefix_misses"] += 1
+            t_admit = entry.get("t_admit")
             state = {"req": req, "prompt": prompt, "gen": [],
                      "replay_left": list(replay), "pages": all_ids,
                      "admit_seq": self._admit_seq,
                      "t_submit": entry["t_submit"],
+                     "t_admit": now if t_admit is None else t_admit,
+                     "times": list(entry.get("times", ())),
                      "retries": int(entry.get("retries", 0))}
             self._admit_seq += 1
             self.stats["admitted"] += 1
@@ -916,21 +975,23 @@ class ServeEngine:
             # the rest replays through forced decode steps
             tok = int(state["replay_left"].pop(0))
         else:
-            if req.rid in self._poison_rids \
-                    or not bool(np.isfinite(np.asarray(last_logits)).all()):
-                # non-finite prefill logits: quarantine before the slot
-                # commits a garbage first token (a 1-token request would
-                # otherwise *finish* with it)
-                self._poison_rids.discard(req.rid)
-                self._quarantine_slot(slot, finished)
-                return
-            s = len(state["prompt"])
-            tok = int(self._sample1(
-                last_logits, jnp.float32(req.temperature),
-                jnp.int32(req.top_k),
-                sampling.slot_key(jnp.asarray(self._base_keys[slot]),
-                                  jnp.int32(s))))
-        state["gen"] = [tok]
+            # the reads below wait for the prefill's device work
+            with span("serve.first_token"):
+                if req.rid in self._poison_rids or not bool(
+                        np.isfinite(np.asarray(last_logits)).all()):
+                    # non-finite prefill logits: quarantine before the slot
+                    # commits a garbage first token (a 1-token request
+                    # would otherwise *finish* with it)
+                    self._poison_rids.discard(req.rid)
+                    self._quarantine_slot(slot, finished)
+                    return
+                s = len(state["prompt"])
+                tok = int(self._sample1(
+                    last_logits, jnp.float32(req.temperature),
+                    jnp.int32(req.top_k),
+                    sampling.slot_key(jnp.asarray(self._base_keys[slot]),
+                                      jnp.int32(s))))
+        _commit(state, tok, self._clock())
         self._active[slot] = True
         self._last_tok[slot] = tok
         self._maybe_finish(slot, finished)
@@ -951,19 +1012,21 @@ class ServeEngine:
         C = cp * page
         start = st["prefill_pos"]
         end = min(s, start + C)
-        toks = np.zeros((C,), np.int32)
-        toks[:end - start] = prompt[start:end]
-        m = st["shared_pages"]
-        pids = np.zeros((cp,), np.int32)
-        p0 = start // page
-        for j in range(cp):
-            gp = p0 + j
-            if m <= gp < self.max_pages_per_seq:
-                pids[j] = self._bt[slot, gp]      # 0 (null) when shared
-        logits, self.pool = self._chunk_jit()(
-            self.params, self.pool, jnp.asarray(toks), jnp.int32(start),
-            jnp.asarray(pids), jnp.int32(end), jnp.asarray(self._bt[slot]),
-            jnp.int32(min(s - 1 - start, C - 1)))
+        with span("serve.prefill_chunk"):
+            toks = np.zeros((C,), np.int32)
+            toks[:end - start] = prompt[start:end]
+            m = st["shared_pages"]
+            pids = np.zeros((cp,), np.int32)
+            p0 = start // page
+            for j in range(cp):
+                gp = p0 + j
+                if m <= gp < self.max_pages_per_seq:
+                    pids[j] = self._bt[slot, gp]  # 0 (null) when shared
+            logits, self.pool = self._chunk_jit()(
+                self.params, self.pool, jnp.asarray(toks), jnp.int32(start),
+                jnp.asarray(pids), jnp.int32(end),
+                jnp.asarray(self._bt[slot]),
+                jnp.int32(min(s - 1 - start, C - 1)))
         self.stats["prefill_tokens"] += end - start
         self.stats["prefill_chunks"] += 1
         st["prefill_pos"] = start + C
@@ -985,9 +1048,7 @@ class ServeEngine:
         replay = entry["replay"]
         tokens = np.concatenate([entry["prompt"], replay])
         self.stats["finished"] += 1
-        return Finished(rid=entry["req"].rid, tokens=tokens,
-                        prompt_len=len(entry["prompt"]),
-                        n_generated=int(replay.size), reason=reason)
+        return _finished(entry, tokens, int(replay.size), reason)
 
     def _maybe_finish(self, slot: int, finished: list) -> bool:
         state = self._slots[slot]
@@ -1007,10 +1068,8 @@ class ServeEngine:
         self._lens[slot] = 0
         self._slots[slot] = None
         self.stats["finished"] += 1
-        finished.append(Finished(
-            rid=req.rid, tokens=self._full_tokens(state),
-            prompt_len=len(state["prompt"]), n_generated=n_gen,
-            reason=reason))
+        finished.append(_finished(state, self._full_tokens(state), n_gen,
+                                  reason))
         return True
 
     def _quarantine_slot(self, slot: int, finished: list) -> None:
@@ -1025,7 +1084,6 @@ class ServeEngine:
         back to zeros before the free; shared pages (prefix hits, trie
         refs) were minted before the poison and stay as they are."""
         state = self._slots[slot]
-        req = state["req"]
         private = [p for p in state["pages"]
                    if self.allocator.refcount(p) == 1]
         if private:
@@ -1037,10 +1095,8 @@ class ServeEngine:
         self._slots[slot] = None
         self.stats["quarantined"] += 1
         self.stats["finished"] += 1
-        finished.append(Finished(
-            rid=req.rid, tokens=self._full_tokens(state),
-            prompt_len=len(state["prompt"]),
-            n_generated=len(state["gen"]), reason="nan"))
+        finished.append(_finished(state, self._full_tokens(state),
+                                  len(state["gen"]), "nan"))
 
     def _page_checksum(self, pid: int) -> int:
         """CRC32 over one pool page's raw code (and scale) bytes across all
@@ -1048,14 +1104,15 @@ class ServeEngine:
         insert and re-verifies at use, so a corrupted shared page is caught
         before a new sharer ever attends it."""
         pid = int(pid)
-        parts = [np.asarray(self.pool.k_pages[:, pid]),
-                 np.asarray(self.pool.v_pages[:, pid])]
-        if self.plan.kv_bits:
-            parts += [np.asarray(self.pool.k_scale[:, pid]),
-                      np.asarray(self.pool.v_scale[:, pid])]
-        crc = 0
-        for a in parts:
-            crc = zlib.crc32(np.ascontiguousarray(a).tobytes(), crc)
+        with span("serve.page_checksum"):
+            parts = [np.asarray(self.pool.k_pages[:, pid]),
+                     np.asarray(self.pool.v_pages[:, pid])]
+            if self.plan.kv_bits:
+                parts += [np.asarray(self.pool.k_scale[:, pid]),
+                          np.asarray(self.pool.v_scale[:, pid])]
+            crc = 0
+            for a in parts:
+                crc = zlib.crc32(np.ascontiguousarray(a).tobytes(), crc)
         return crc
 
     def _inject_kv_flip(self, spec) -> None:
@@ -1097,6 +1154,8 @@ class ServeEngine:
         self._queue.appendleft({"req": state["req"],
                                 "prompt": state["prompt"], "replay": replay,
                                 "t_submit": state["t_submit"],
+                                "t_admit": state["t_admit"],
+                                "times": state["times"],
                                 "retries": int(state.get("retries", 0)) + 1})
         self.stats["preemptions"] += 1
         return slot
@@ -1186,7 +1245,8 @@ class ServeEngine:
         draft_np = np.asarray(draft)
         tgt_np = np.asarray(tgt)               # blocks until ready
         ok_np = np.asarray(ok)
-        dt = self._clock() - t0
+        t1 = self._clock()
+        dt = t1 - t0
 
         committed = 0
         for slot in range(self.max_slots):
@@ -1209,7 +1269,7 @@ class ServeEngine:
             for tok in tgt_np[slot, :m + 1]:
                 tok = int(tok)
                 self._lens[slot] += 1
-                state["gen"].append(tok)
+                _commit(state, tok, t1)
                 self._last_tok[slot] = tok
                 committed += 1
                 if self._maybe_finish(slot, finished):
@@ -1230,6 +1290,10 @@ class ServeEngine:
         chunk, decode one token for every live sequence — or, with
         ``spec_decode``, run one speculative window (up to k+1 tokens per
         slot). Returns the requests that finished."""
+        with span("serve.step"):
+            return self._step()
+
+    def _step(self) -> list[Finished]:
         finished: list[Finished] = []
         self._step_no += 1
         if self._faults is not None:
@@ -1252,7 +1316,8 @@ class ServeEngine:
             if bits != self.weight_bits and not self._replaying():
                 self.set_weight_bits(bits)
         pt0 = self.stats["prefill_tokens"]
-        self._admit(finished)
+        with span("serve.admit"):
+            self._admit(finished)
         if self._chunked:
             self._advance_prefills(finished)
         self._ensure_pages()
@@ -1267,20 +1332,24 @@ class ServeEngine:
         # when the autoscaler sits at/below the draft's bits, or when any
         # active slot lacks k+1 rows of page runway.
         if self._spec_ready() and self._ensure_spec_pages():
-            self._spec_step(finished)
+            with span("serve.spec"):
+                self._spec_step(finished)
             return finished
 
         sampled = bool((self._temps[self._active] > 0).any())
         t0 = self._clock()
-        tok, ok, self.pool = self._decode_jit(sampled)(
-            self.params, self.pool,
-            jnp.asarray(self._last_tok)[:, None],
-            jnp.asarray(self._lens), jnp.asarray(self._bt),
-            jnp.asarray(self._active), jnp.asarray(self._base_keys),
-            jnp.asarray(self._temps), jnp.asarray(self._topks))
-        tok_np = np.asarray(tok)               # blocks until ready
-        ok_np = np.asarray(ok)
-        dt = self._clock() - t0
+        with span("serve.decode.dispatch"):
+            tok, ok, self.pool = self._decode_jit(sampled)(
+                self.params, self.pool,
+                jnp.asarray(self._last_tok)[:, None],
+                jnp.asarray(self._lens), jnp.asarray(self._bt),
+                jnp.asarray(self._active), jnp.asarray(self._base_keys),
+                jnp.asarray(self._temps), jnp.asarray(self._topks))
+        with span("serve.decode.wait"):
+            tok_np = np.asarray(tok)           # blocks until ready
+            ok_np = np.asarray(ok)
+        t1 = self._clock()                     # every token's commit time
+        dt = t1 - t0
         n_live = int(self._active.sum())
         self.stats["decode_steps"] += 1
         self.stats["decode_tokens"] += n_live
@@ -1290,7 +1359,14 @@ class ServeEngine:
             self.stats["steady_decode_tokens"] += n_live
             self.decode_times.append(dt)
         self._compiled_variants.add(variant)
+        with span("serve.commit"):
+            self._commit_decoded(tok_np, ok_np, t1, finished)
+        return finished
 
+    def _commit_decoded(self, tok_np, ok_np, now: float,
+                        finished: list) -> None:
+        """Commit one decode step's token to every live slot; finish and
+        free the slots that are done."""
         for slot in range(self.max_slots):
             if not self._active[slot]:
                 continue
@@ -1311,10 +1387,9 @@ class ServeEngine:
             else:
                 tok = int(tok_np[slot])
             self._lens[slot] += 1
-            state["gen"].append(tok)
+            _commit(state, tok, now)
             self._last_tok[slot] = tok
             self._maybe_finish(slot, finished)
-        return finished
 
     def run(self, requests=None, max_steps: int = 100_000) -> dict[int, Finished]:
         """Serve until the queue drains and every sequence finishes."""

@@ -12,6 +12,10 @@ is ``fold_in(state.rng, state.step)``; it splits into the same three lanes
 the legacy step used — kq (model channel / QAT), kg (grad channel), km
 (quantized moments) — plus a fourth derived lane for the sample channel
 (inactive outside the 'e2e' plan mode, so legacy numerics are unchanged).
+
+The step jits as ``train_step``, with its phases under the named scopes
+``sample_channel``, ``forward_backward``, ``grad_channel`` and ``optimizer``
+(the op metadata a profiler trace attributes device time by).
 """
 from __future__ import annotations
 
@@ -85,23 +89,27 @@ def make_step(cfg: T.ModelConfig, opt_cfg: adamw.AdamWConfig,
         default_channels(cfg.precision)
     grads_of = make_grads_fn(cfg, channels["model"], accum_steps)
 
-    def step(state: TrainState, batch):
+    def train_step(state: TrainState, batch):
         key = jax.random.fold_in(state.rng, state.step)
         kq, kg, km = jax.random.split(key, 3)
         ks = jax.random.fold_in(key, 3)
 
         ch = dict(state.channels)
-        batch, ch["sample"] = channels["sample"].apply(
-            batch, ch.get("sample", {}), ks)
-        loss_val, grads = grads_of(state.params, batch, kq)
-        grads, ch["grad"] = channels["grad"].apply(
-            grads, ch.get("grad", {}), kg)
+        with jax.named_scope("sample_channel"):
+            batch, ch["sample"] = channels["sample"].apply(
+                batch, ch.get("sample", {}), ks)
+        with jax.named_scope("forward_backward"):
+            loss_val, grads = grads_of(state.params, batch, kq)
+        with jax.named_scope("grad_channel"):
+            grads, ch["grad"] = channels["grad"].apply(
+                grads, ch.get("grad", {}), kg)
         mkey = km if opt_cfg.moment_bits else None
-        params, opt, metrics = adamw.apply_updates(
-            state.params, grads, state.opt, opt_cfg, key=mkey)
+        with jax.named_scope("optimizer"):
+            params, opt, metrics = adamw.apply_updates(
+                state.params, grads, state.opt, opt_cfg, key=mkey)
         metrics["loss"] = loss_val
         new_state = TrainState(params, opt, ch, state.step + 1, state.rng,
                                state.epoch)
         return new_state, metrics
 
-    return step
+    return train_step

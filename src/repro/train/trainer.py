@@ -120,13 +120,16 @@ class Trainer:
     # ----------------------------------------------------------------- step --
     def step(self, state: TrainState, batch: dict):
         """One training step. ``batch`` must be the one at ``state.step``
-        (numpy or jnp leaves; vlm runs get zero vision stand-ins)."""
-        batch_j = {k: jnp.asarray(v) for k, v in batch.items()}
-        if self.cfg.family == "vlm" and "vision" not in batch_j:
-            b = batch_j["tokens"].shape[0]
-            batch_j["vision"] = jnp.zeros(
-                (b, self.cfg.n_vis_tokens, self.cfg.d_model), jnp.float32)
-        return self._step_fn(state, batch_j)
+        (numpy or jnp leaves; vlm runs get zero vision stand-ins). The
+        upload and the dispatch run in the host span ``train.step``."""
+        with jax.profiler.TraceAnnotation("train.step"):
+            batch_j = {k: jnp.asarray(v) for k, v in batch.items()}
+            if self.cfg.family == "vlm" and "vision" not in batch_j:
+                b = batch_j["tokens"].shape[0]
+                batch_j["vision"] = jnp.zeros(
+                    (b, self.cfg.n_vis_tokens, self.cfg.d_model),
+                    jnp.float32)
+            return self._step_fn(state, batch_j)
 
     # ---------------------------------------------------------- checkpoints --
     def _manifest_format(self, step: int | None) -> str | None:
@@ -236,10 +239,10 @@ class Trainer:
                         and (fail_count is None or fired < fail_count)):
                     fired += 1
                     raise RuntimeError("injected fault (test)")
-                t0 = time.time()
+                t0 = time.perf_counter()
                 state, metrics = self.step(state, batch_np)
                 loss = float(metrics["loss"])
-                dt = time.time() - t0
+                dt = time.perf_counter() - t0
                 if self.monitor.record(dt):
                     print(f"[train] step {step_i}: straggler flagged ({dt:.3f}s)")
                 losses.append(loss)
