@@ -145,6 +145,7 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation as span
 
+from repro.kernels import paged_attn as pa_mod
 from repro.kernels import registry
 from repro.kernels.ops import kv_bits_of
 from repro.kernels.ref import dequant_pages_ref, gather_pages_ref
@@ -287,7 +288,12 @@ class ServeEngine:
                       "prefix_hit_tokens": 0, "spec_steps": 0,
                       "spec_draft_tokens": 0, "spec_accepted_tokens": 0,
                       "quarantined": 0, "retries_exhausted": 0,
-                      "kv_flips": 0}
+                      "kv_flips": 0, "attn_blocks": 0}
+        # page blocks of kernels/paged_attn.py: ``attn_blocks`` sums, over
+        # decode steps, the blocks one layer's call reads for the live slots
+        self._attn_ppb = pa_mod.pages_per_block(
+            self.page_size, self.max_pages_per_seq, cfg.n_kv_heads,
+            cfg.head_dim, plan.kv_bits)
         self.admit_waits: list[float] = []      # per-admission queue wait, s
         self.decode_times: list[float] = []     # steady per-step decode, s
         self._clock = clock if clock is not None else time.perf_counter
@@ -1337,6 +1343,8 @@ class ServeEngine:
             return finished
 
         sampled = bool((self._temps[self._active] > 0).any())
+        pages = -(-(self._lens[self._active] + 1) // self.page_size)
+        self.stats["attn_blocks"] += int((-(-pages // self._attn_ppb)).sum())
         t0 = self._clock()
         with span("serve.decode.dispatch"):
             tok, ok, self.pool = self._decode_jit(sampled)(

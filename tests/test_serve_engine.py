@@ -13,6 +13,7 @@ import pytest
 
 from repro import configs
 from repro.kernels import registry
+from repro.kernels import paged_attn as pa_mod
 from repro.kernels import ref as kref
 from repro.models import attention as attn
 from repro.models import transformer as T
@@ -46,6 +47,34 @@ def _pool_from_rows(k, v, page, n_pages, kv_bits):
                                jnp.asarray(ids, jnp.int32))
         bt[i] = ids
     return pool, jnp.asarray(bt)
+
+
+def _paged_planes(rng, n_pages, page, g, d, kv_bits):
+    """Random K/V code planes and scales of a one-layer pool: (k, v, k_scale,
+    v_scale), the scales None for bf16."""
+    kv = rng.normal(0, 1, (2, n_pages, page, g, d)).astype(np.float32)
+    if not kv_bits:
+        return (jnp.asarray(kv[0], jnp.bfloat16),
+                jnp.asarray(kv[1], jnp.bfloat16), None, None)
+    sch = pg.kv_scheme(kv_bits)
+    qk = encode(jnp.asarray(kv[0]), sch)
+    qv = encode(jnp.asarray(kv[1]), sch)
+    return qk.codes, qv.codes, qk.scale, qv.scale
+
+
+# kernel parity at lengths on the edges of a page and of a kernel block
+# (page 16: a block is _PPB pages); MAXP 20 is no multiple of the block
+_PAGE = 16
+_PPB = pa_mod.pages_per_block(_PAGE, 20, 2, 16, 8)
+_LENS = {
+    "short": ([0, 1, _PAGE - 1], 20),
+    "page": ([_PAGE, _PPB * _PAGE, _PPB * _PAGE + 1], 20),
+    "full": ([20 * _PAGE, 3, 20 * _PAGE - 1], 20),
+}
+_PALLAS_CASES = [pytest.param(kv, [17, 3, 29], 4, 8, id=str(kv))
+                 for kv in (0, 8, 4)] + [
+    pytest.param(kv, lens, maxp, _PAGE, id=f"{kv}-{name}")
+    for kv in (0, 8, 4) for name, (lens, maxp) in _LENS.items()]
 
 
 class TestPagedAttentionParity:
@@ -109,31 +138,75 @@ class TestPagedAttentionParity:
         np.testing.assert_array_equal(np.asarray(paged_v, np.float32),
                                       np.asarray(ring_v, np.float32))
 
-    @pytest.mark.parametrize("kv_bits", [0, 8, 4])
-    def test_pallas_matches_ref(self, kv_bits):
+    @pytest.mark.parametrize("kv_bits,lens,maxp,page", _PALLAS_CASES)
+    def test_pallas_matches_ref(self, kv_bits, lens, maxp, page):
         """The Pallas flash kernel ≈ the gather-ref backend on active rows
-        (f32 streaming softmax vs one-shot bf16 softmax associativity)."""
+        (f32 streaming softmax vs one-shot bf16 softmax associativity), at
+        lengths on every edge of a page and of a kernel block."""
         rng = np.random.default_rng(0)
-        b, h, g, d, page, maxp, n_pages = 3, 4, 2, 16, 8, 4, 12
+        b, h, g, d = len(lens), 4, 2, 16
+        n_pages = b * maxp + 1
         q = jnp.asarray(rng.normal(0, 1, (b, h, d)), jnp.float32)
-        lens = jnp.asarray([17, 3, 29], jnp.int32)
         bt = jnp.asarray(rng.integers(1, n_pages, (b, maxp)), jnp.int32)
-        kv = rng.normal(0, 1, (2, n_pages, page, g, d)).astype(np.float32)
-        if kv_bits:
-            sch = pg.kv_scheme(kv_bits)
-            qk = encode(jnp.asarray(kv[0]), sch)
-            qv = encode(jnp.asarray(kv[1]), sch)
-            args = (qk.codes, qv.codes, qk.scale, qv.scale)
-        else:
-            args = (jnp.asarray(kv[0], jnp.bfloat16),
-                    jnp.asarray(kv[1], jnp.bfloat16), None, None)
+        args = _paged_planes(rng, n_pages, page, g, d, kv_bits)
         r = registry.get("ref").paged_attention(
-            q, *args, bt, lens, softmax_scale=d ** -0.5)
+            q, *args, bt, jnp.asarray(lens, jnp.int32),
+            softmax_scale=d ** -0.5)
         p = registry.get("pallas").paged_attention(
-            q, *args, bt, lens, softmax_scale=d ** -0.5)
-        err = float(jnp.max(jnp.abs(r.astype(jnp.float32)
-                                    - p.astype(jnp.float32))))
+            q, *args, bt, jnp.asarray(lens, jnp.int32),
+            softmax_scale=d ** -0.5)
+        live = np.asarray(lens) > 0           # an empty slot's output is 0
+        r, p = np.asarray(r, np.float32), np.asarray(p, np.float32)
+        err = float(np.max(np.abs(r[live] - p[live])))
         assert err < 2e-2, err
+        assert not p[~live].any()
+
+    @pytest.mark.parametrize("kv_bits", [0, 8, 4])
+    def test_pallas_never_reads_dead_pages(self, kv_bits):
+        """Pages the block table lists past a slot's last live page, and the
+        rows past its length inside that page, may hold anything: NaN scales,
+        inf bf16 values. The kernel's output stays finite and equals the ref
+        run on a clean pool at the same live rows."""
+        rng = np.random.default_rng(1)
+        h, g, d, page, maxp = 4, 2, 16, _PAGE, 20
+        lens = np.array([3, _PPB * page + 5, 0, 2 * page], np.int32)
+        b = len(lens)
+        n_live = b * maxp + 1
+        n_pages = n_live + 8                 # 8 poisoned pages at the end
+        q = jnp.asarray(rng.normal(0, 1, (b, h, d)), jnp.float32)
+        kp, vp, ks, vs = _paged_planes(rng, n_pages, page, g, d, kv_bits)
+        live = -(-lens // page)
+        clean = np.zeros((b, maxp), np.int32)
+        poison = np.zeros((b, maxp), np.int32)
+        for i in range(b):
+            ids = 1 + i * maxp + np.arange(maxp)
+            clean[i, :live[i]] = poison[i, :live[i]] = ids[:live[i]]
+            poison[i, live[i]:] = rng.integers(n_live, n_pages,
+                                               maxp - live[i])
+        # poison the dead pages and every row past the length in live ones
+        dead_rows = np.zeros((n_pages, page), bool)
+        dead_rows[n_live:] = True
+        for i in range(b):
+            if lens[i] % page:
+                dead_rows[clean[i, live[i] - 1], lens[i] % page:] = True
+        m = jnp.asarray(dead_rows)[:, :, None, None]
+        if kv_bits:
+            pk, pv = kp, vp
+            sk, sv = (jnp.where(m, jnp.nan, x) for x in (ks, vs))
+        else:
+            pk, pv = (jnp.where(m, jnp.inf, x) for x in (kp, vp))
+            sk = sv = None
+        want = registry.get("ref").paged_attention(
+            q, kp, vp, ks, vs, jnp.asarray(clean), jnp.asarray(lens),
+            softmax_scale=d ** -0.5)
+        got = registry.get("pallas").paged_attention(
+            q, pk, pv, sk, sv, jnp.asarray(poison), jnp.asarray(lens),
+            softmax_scale=d ** -0.5)
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got[lens > 0],
+                                   np.asarray(want, np.float32)[lens > 0],
+                                   atol=2e-2)
 
 
 def _cfg():
@@ -697,6 +770,42 @@ class TestSchedulerBugfixes:
         for rid in out:
             np.testing.assert_array_equal(out[rid].tokens, fixed[rid].tokens)
         eng.allocator.check_leaks(0)
+
+
+class TestAttnBlocksCounter:
+    def test_attn_blocks_counts_live_blocks_per_decode_step(self):
+        """``stats['attn_blocks']`` = Σ over decode steps and active slots of
+        cdiv(cdiv(len, page), pages_per_block), ``len`` counting the row the
+        step appends — read here from the arguments each step dispatches."""
+        cfg = _cfg()
+        page = 16
+        eng = ServeEngine(_params(cfg), cfg, plan=PrecisionPlan(kv_bits=8),
+                          max_slots=3, page_size=page, max_seq_len=320)
+        ppb = pa_mod.pages_per_block(page, eng.max_pages_per_seq,
+                                     cfg.n_kv_heads, cfg.head_dim, 8)
+        assert ppb * page < 250 + 8          # one slot crosses a block
+        seen = []
+        wrapped = eng._decode_jit
+
+        def spy(sampled):
+            fn = wrapped(sampled)
+
+            def call(params, pool, tokens, positions, bt, active, *rest):
+                seen.append((np.array(positions), np.array(active)))
+                return fn(params, pool, tokens, positions, bt, active, *rest)
+            return call
+
+        eng._decode_jit = spy
+        rng = np.random.default_rng(3)
+        eng.run([Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, n),
+                         max_new_tokens=8)
+                 for i, n in enumerate([5, 250, 60])])
+        want = 0
+        for pos, act in seen:
+            pages = -(-(pos[act] + 1) // page)
+            want += int((-(-pages // ppb)).sum())
+        assert seen and eng.stats["attn_blocks"] == want
+        assert want > sum(int(a.sum()) for _, a in seen)   # some take two
 
 
 class TestKVBytesAccounting:

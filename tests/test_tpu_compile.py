@@ -3,10 +3,11 @@
 Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 unsupported casts, blocks off the (8, 128) tiling, VMEM overruns. These cases
 hand each kernel entry point shapes at gemma-2b widths (M=256, K=2048,
-N=16384; paged decode with H=8, Hkv=1, D=256, page 16) placed on one chip of
-a described ``v5e:2x2`` topology, compile it with the TPU compiler, and check
-that the compiled program holds the kernel (``tpu_custom_call``). Nothing
-runs; no chip is needed.
+N=16384; paged decode with H=8, Hkv=1, D=256, page 16, and once more at
+granite-3-8b's serving cell: 48 slots, H=32, Hkv=8, D=128, 96 pages a slot)
+placed on one chip of a described ``v5e:2x2`` topology, compile it with the
+TPU compiler, and check that the compiled program holds the kernel
+(``tpu_custom_call``). Nothing runs; no chip is needed.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -111,18 +112,19 @@ def _qmv(s):
     return (qmm_mod.qmv, (s((4096, K), jnp.int8), s((K, 1), jnp.float32)))
 
 
-def _paged_attn(kv_bits):
+def _paged_attn(kv_bits, b=B, h=H, hkv=HKV, d=D, page=PAGE, maxp=MAXP,
+                pool=POOL):
     def build(s):
-        dp = D // 2 if kv_bits == 4 else D
+        dp = d // 2 if kv_bits == 4 else d
         dt = {0: jnp.bfloat16, 8: jnp.int8, 4: jnp.uint8}[kv_bits]
-        sc = (POOL, PAGE, HKV, 1) if kv_bits else (1, 1, HKV, 1)
+        sc = (pool, page, hkv, 1) if kv_bits else (1, 1, hkv, 1)
         return (lambda q, kp, vp, ks, vs, bt, ln: pa_mod.paged_decode_attn(
-                    q, kp, vp, ks, vs, bt, ln, softmax_scale=D ** -0.5,
+                    q, kp, vp, ks, vs, bt, ln, softmax_scale=d ** -0.5,
                     kv_bits=kv_bits),
-                (s((B, H, D), jnp.bfloat16), s((POOL, PAGE, HKV, dp), dt),
-                 s((POOL, PAGE, HKV, dp), dt), s(sc, jnp.float32),
-                 s(sc, jnp.float32), s((B, MAXP), jnp.int32),
-                 s((B,), jnp.int32)))
+                (s((b, h, d), jnp.bfloat16), s((pool, page, hkv, dp), dt),
+                 s((pool, page, hkv, dp), dt), s(sc, jnp.float32),
+                 s(sc, jnp.float32), s((b, maxp), jnp.int32),
+                 s((b,), jnp.int32)))
     return build
 
 
@@ -154,6 +156,9 @@ CASES = {
     "paged_attn_bf16": _paged_attn(0),
     "paged_attn_int8": _paged_attn(8),
     "paged_attn_int4": _paged_attn(4),
+    # granite-3-8b's serving cell: 48 slots, GQA 32/8, D 128, 96 pages a slot
+    "paged_attn_int8_granite": _paged_attn(8, b=48, h=32, hkv=8, d=128,
+                                           maxp=96, pool=2475),
     "quant_adamw": _quant_adamw,
 }
 
